@@ -122,13 +122,15 @@ def bench_specs() -> None:
     entry in the backend registry, so the CI ``--quick`` smoke exercises
     each lowering path.  Only the real ``pallas`` backend may be
     unavailable (it needs a TPU; on CPU the row reports the failure) —
-    any other backend error propagates and fails the smoke.
+    any other backend error propagates and fails the smoke.  On a TPU
+    every error propagates, the real ``pallas`` one included.
     """
     import jax
 
     from benchmarks import serve_pointcloud as sp
     from repro.api import BACKENDS, lite_spec
     from repro.data import pointclouds
+    from repro.kernels.tuning import on_tpu
     from repro.models import pointmlp as PM
     from repro.serve.pointcloud import PointCloudEngine
 
@@ -149,7 +151,7 @@ def bench_specs() -> None:
             derived = (f"backend={backend};precision={spec.precision};"
                        f"SPS={sps:.1f}")
         except Exception as e:
-            if backend != "pallas":     # only the TPU path may be absent
+            if backend != "pallas" or on_tpu():  # absent only off-TPU
                 raise
             derived = (f"backend={backend};"
                        f"unavailable={type(e).__name__}")
@@ -512,6 +514,8 @@ def main() -> None:
     ap.add_argument("--table1-steps", type=int, default=120)
     ap.add_argument("--fig4-steps", type=int, default=100)
     args = ap.parse_args()
+    from repro.launch.profile import configure_compile_cache
+    configure_compile_cache()
 
     print("name,us_per_call,derived")
     if args.kernels_quick:

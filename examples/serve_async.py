@@ -29,6 +29,7 @@ import numpy as np  # noqa: E402
 from repro.api import BACKENDS, lite_spec  # noqa: E402
 from repro.api.build import build  # noqa: E402
 from repro.data import pointclouds  # noqa: E402
+from repro.launch.profile import configure_compile_cache  # noqa: E402
 from repro.models import pointmlp as PM  # noqa: E402
 from repro.serve.async_engine import AsyncPointCloudEngine  # noqa: E402
 from repro.serve.policy import POLICIES  # noqa: E402
@@ -102,7 +103,9 @@ def main() -> None:
     ap.add_argument("--backend", choices=sorted(BACKENDS.names()),
                     default="ref")
     ap.add_argument("--seed", type=int, default=0)
-    asyncio.run(serve(ap.parse_args()))
+    args = ap.parse_args()
+    configure_compile_cache()
+    asyncio.run(serve(args))
 
 
 if __name__ == "__main__":

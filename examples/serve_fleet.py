@@ -27,6 +27,7 @@ import jax  # noqa: E402
 
 from repro.api import FleetSpec, TenantSpec, lite_spec  # noqa: E402
 from repro.data import pointclouds  # noqa: E402
+from repro.launch.profile import configure_compile_cache  # noqa: E402
 from repro.models import pointmlp as PM  # noqa: E402
 from repro.serve.fleet import Overloaded, PipelineFleet  # noqa: E402
 from repro.serve.router import ROUTERS  # noqa: E402
@@ -45,6 +46,7 @@ def main() -> None:
                     help="burst size fired at the lidar tenant")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     # The pool: the same tiny model served at two precisions.  A real
     # deployment would put elite_spec/m2_spec variants here — any

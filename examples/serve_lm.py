@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.quant import QuantConfig, quantize_tree
+from repro.launch.profile import configure_compile_cache
 from repro.models.api import get_model
 from repro.serve.engine import Engine
 
@@ -32,6 +33,7 @@ def main():
                     help="full published config (TPU-scale)")
     ap.add_argument("--temperature", type=float, default=0.8)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = (get_config if args.full else get_smoke_config)(args.arch)
     api = get_model(cfg)

@@ -23,6 +23,7 @@ import jax  # noqa: E402
 
 from repro.api import PipelineSpec, lite_spec  # noqa: E402
 from repro.data import pointclouds  # noqa: E402
+from repro.launch.profile import configure_compile_cache  # noqa: E402
 from repro.models import pointmlp as PM  # noqa: E402
 from repro.serve.pointcloud import PointCloudEngine  # noqa: E402
 
@@ -42,6 +43,7 @@ def main() -> None:
                     help="miniature-train first (0 = random weights demo)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     spec = lite_spec(pointclouds.N_CLASSES)
     if args.train_steps > 0:

@@ -35,6 +35,7 @@ import numpy as np  # noqa: E402
 
 from repro.api import build, lite_spec  # noqa: E402
 from repro.data import pointclouds  # noqa: E402
+from repro.launch.profile import configure_compile_cache  # noqa: E402
 from repro.models import pointmlp as PM  # noqa: E402
 from repro.serve.pointcloud import PointCloudEngine  # noqa: E402
 
@@ -48,6 +49,7 @@ def main() -> None:
                          "the temporal cache")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     spec = lite_spec(pointclouds.N_CLASSES).replace(
         n_points=args.n_points, embed_dim=16, k_neighbors=8,

@@ -43,6 +43,8 @@ CODES = {
     "RPA014": (ERROR, "stream grouper lacks the neighbor_index/"
                       "group_with_idx split"),
     "RPA015": (ERROR, "stream sampler does not declare advances_state"),
+    "RPA016": (ERROR, "fused op does not compile on a TPU"),
+    "RPA017": (ERROR, "interpret-mode Pallas kernel on a TPU"),
     "RPA020": (ERROR, "data_shards > 1 requires per_sample_norm"),
     "RPA030": (ERROR, "stream session over a non-streaming pipeline"),
     # --- soft misconfigurations (escalated in-tree via the code

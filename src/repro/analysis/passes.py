@@ -40,6 +40,7 @@ from repro.analysis.findings import Finding, finding
 from repro.api import registry
 from repro.api.plan import _PALLAS_BACKENDS
 from repro.api.spec import N_STAGES
+from repro.kernels import tuning
 
 SCOPES = ("lowering", "serving", "placement", "perf")
 
@@ -174,6 +175,30 @@ def stream_contract(spec) -> List[Finding]:
                 f"stream=True needs a sampler declaring its "
                 f"advances_state stream-cache semantics; sampler "
                 f"{spec.sampler!r} does not"))
+    return out
+
+
+@register_pass("tpu-platform", scope="lowering")
+def tpu_platform(spec) -> List[Finding]:
+    """RPA016-017: what would not run compiled on a TPU.  Checked only
+    when JAX's default backend is a TPU, so the CPU keeps its
+    interpret-mode canaries."""
+    if not tuning.on_tpu():
+        return []
+    out: List[Finding] = []
+    backends = (spec.backend,) + tuple(spec.stage_backend or ())
+    if "pallas_interpret" in backends:
+        out.append(finding(
+            "RPA017", "spec.backend",
+            "backend 'pallas_interpret' would run every Pallas kernel in "
+            "interpret mode on the TPU; use backend='pallas'"))
+    if spec.fused_group == "grouped_transfer":
+        # Mosaic rejects the kernel's in-kernel ``jnp.take`` gather
+        # ("Shape mismatch in input, indices and output").
+        out.append(finding(
+            "RPA016", "spec.fused_group",
+            "fused_group='grouped_transfer' does not compile on a TPU "
+            "(Mosaic rejects its in-kernel gather); use fused_group='none'"))
     return out
 
 

@@ -34,6 +34,7 @@ import warnings
 from typing import Any, Dict, Iterable, List, Tuple
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 from repro.analysis.findings import Finding, dedupe, finding
@@ -124,7 +125,7 @@ def _scan_one(jaxpr, where: str, in_shard_region: bool,
                     f"primitive {prim!r} produces float64 "
                     f"{getattr(var.aval, 'shape', ())}"))
         # --- int8->float taint: seed, consume, propagate -------------
-        in_tainted = any(not isinstance(v, jax.core.Literal)
+        in_tainted = any(not isinstance(v, jax.extend.core.Literal)
                          and v in tainted for v in eqn.invars)
         if prim == "convert_element_type":
             src = eqn.invars[0]

@@ -31,10 +31,14 @@ def _knn_kernel(s_ref, p_ref, idx_ref, *, k: int, n_valid: int):
     # mask out padding points (wrapper pads N up to the lane multiple)
     d = jnp.where(col < n_valid, d, big)
 
+    # Column j of the output is written with a select against this iota
+    # (Mosaic has no dynamic_update_slice).
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (d.shape[0], k), 1)
+
     def body(j, carry):
         dist, idx = carry
         am = jnp.argmin(dist, axis=1).astype(jnp.int32)  # [TS]
-        idx = jax.lax.dynamic_update_slice(idx, am[:, None], (0, j))
+        idx = jnp.where(kcol == j, am[:, None], idx)
         # the paper's trick: selected entry := numeric max of the format
         dist = jnp.where(col == am[:, None], big, dist)
         return dist, idx

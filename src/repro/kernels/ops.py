@@ -1,9 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels are *targeted* at TPU and validated in interpret mode against
-``ref.py``).  On a real TPU backend the same entry points compile to
-Mosaic.
+Every kernel resolves ``interpret=None`` from the platform
+(``repro.kernels.tuning.resolve_interpret``): compiled to Mosaic on a
+TPU, interpreted (and validated against ``ref.py``) elsewhere.
 """
 from __future__ import annotations
 
@@ -18,12 +17,8 @@ from repro.kernels.int8_matmul import int8_matmul_pallas, w8_matmul_pallas
 from repro.kernels.knn import knn_pallas
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def knn(samples: jnp.ndarray, points: jnp.ndarray, k: int) -> jnp.ndarray:
-    return knn_pallas(samples, points, k, interpret=_interp())
+    return knn_pallas(samples, points, k)
 
 
 def knn_batched(samples: jnp.ndarray, points: jnp.ndarray, k: int
@@ -32,11 +27,11 @@ def knn_batched(samples: jnp.ndarray, points: jnp.ndarray, k: int
 
 
 def fps(points: jnp.ndarray, n_samples: int) -> jnp.ndarray:
-    return fps_pallas(points, n_samples, interpret=_interp())
+    return fps_pallas(points, n_samples)
 
 
 def fps_update(points_t, last, dists):
-    return fps_update_pallas(points_t, last, dists, interpret=_interp())
+    return fps_update_pallas(points_t, last, dists)
 
 
 def int8_matmul(x: jnp.ndarray, w_q: jnp.ndarray, w_scale: jnp.ndarray,
@@ -52,8 +47,7 @@ def int8_matmul(x: jnp.ndarray, w_q: jnp.ndarray, w_scale: jnp.ndarray,
     tm, tk, tn = tiles if tiles is not None else (128, 128, 128)
     y = int8_matmul_pallas(x_q.reshape(-1, x.shape[-1]), w_q, scale,
                            tm=tm, tk=tk, tn=tn, out_dtype=jnp.float32,
-                           interpret=(_interp() if interpret is None
-                                      else interpret))
+                           interpret=interpret)
     return y.reshape(*lead, w_q.shape[1]).astype(x.dtype)
 
 
@@ -61,7 +55,7 @@ def w8_matmul(x: jnp.ndarray, w_q: jnp.ndarray, w_scale: jnp.ndarray
               ) -> jnp.ndarray:
     lead = x.shape[:-1]
     y = w8_matmul_pallas(x.reshape(-1, x.shape[-1]), w_q,
-                         w_scale.reshape(1, -1), interpret=_interp())
+                         w_scale.reshape(1, -1))
     return y.reshape(*lead, w_q.shape[1])
 
 
@@ -69,11 +63,11 @@ def fused_linear(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                  activation: str = "relu") -> jnp.ndarray:
     lead = x.shape[:-1]
     y = fused_linear_pallas(x.reshape(-1, x.shape[-1]), w, b,
-                            activation=activation, interpret=_interp())
+                            activation=activation)
     return y.reshape(*lead, w.shape[1])
 
 
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     tq: int = 128, tk: int = 128) -> jnp.ndarray:
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  tq=tq, tk=tk, interpret=_interp())
+                                  tq=tq, tk=tk)

@@ -73,16 +73,32 @@ class KernelTuning:
 DEFAULT_TUNING = KernelTuning()
 
 
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU — the one platform probe
+    behind interpret resolution and the ``tpu-platform`` lowering pass
+    (tests steer both by patching this function)."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Resolve an ``interpret=None`` kernel default from the platform.
+    """Resolve a kernel's ``interpret`` argument against the platform.
 
     ``None`` means "compile on real Pallas hardware, interpret elsewhere"
     — the lowering layer passes an explicit bool per backend key
     (``pallas_interpret`` forces True), so only direct kernel calls hit
-    this default.  Previously the kernels hardcoded ``interpret=True``,
-    which silently interpreted on TPU too.
+    this default.  ``True`` on a TPU raises (RPA017): a kernel never
+    runs interpreted on the chip without the caller hearing about it.
     """
-    if interpret is not None:
-        return bool(interpret)
-    import jax
-    return jax.default_backend() != "tpu"
+    tpu = on_tpu()
+    if interpret is None:
+        return not tpu
+    if interpret and tpu:
+        # Deferred: repro.analysis imports repro.api, which imports
+        # this module.
+        from repro.analysis import enforce, finding
+        enforce([finding(
+            "RPA017", "interpret",
+            "interpret=True on a TPU: Pallas kernels compile on the "
+            "chip; pass interpret=None (or False) instead")])
+    return bool(interpret)

@@ -17,11 +17,16 @@ exactly how the process was brought up::
 Profiles only *add* settings the environment doesn't already pin —
 an explicit ``XLA_FLAGS`` from the caller always wins — and
 ``apply()`` records what it changed so tests can undo it.
+
+:func:`configure_compile_cache` places JAX's persistent compilation
+cache; entry points call it before their first compile, never at
+import.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
+import pathlib
 from typing import Dict, Optional, Tuple
 
 #: tcmalloc soname the TPU-host recipe preloads (the standard Ubuntu
@@ -97,6 +102,30 @@ PROFILES: Dict[str, LaunchProfile] = {
              ("TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD", "60000000000"),
              ("TF_CPP_MIN_LOG_LEVEL", "4"))),
 }
+
+
+#: The compile cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: a fixed directory of the checkout (listed in ``.gitignore``).  The
+#: path is part of the cache key, so it is never built from a temporary
+#: name, a pid or the time.
+DEFAULT_COMPILE_CACHE = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads that variable
+    itself and this sets no directory.  Otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE`.  Either way every program is cached,
+    not only those that took a second or more to compile, so a warm run
+    compiles nothing.  Call before the first compile."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 
 def launch_profile(platform: Optional[str] = None) -> LaunchProfile:

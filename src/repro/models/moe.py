@@ -23,7 +23,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 
@@ -194,12 +193,13 @@ def moe_apply_local(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
                     axis=(0, 1))
     aux = e * jnp.sum(frac * jnp.mean(probs, axis=0))
 
-    dispatch = compat.shard_map(
+    dispatch = jax.shard_map(
         functools.partial(_dispatch_local, e_local=e_local, cap=cap,
                           dtype=x.dtype),
         mesh=mesh,
         in_specs=(P(dp, None), P(dp, None), P(dp, None)),
-        out_specs=(P("model", dp, None), P("model", dp), P("model", dp)))
+        out_specs=(P("model", dp, None), P("model", dp), P("model", dp)),
+        check_vma=False)
     buf, src, wgt = dispatch(xf, top_e.astype(jnp.int32),
                              top_p.astype(jnp.float32))
     # buf global: [E, n_dp*cap, d] sharded (model, dp, -): expert matmuls
@@ -208,10 +208,10 @@ def moe_apply_local(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
     u = jnp.einsum("ecd,edf->ecf", buf, p["up_w"])
     yb = jnp.einsum("ecf,efd->ecd", L.silu(g) * u, p["down_w"])
 
-    combine = compat.shard_map(
+    combine = jax.shard_map(
         functools.partial(_combine_local, t_loc=t_loc, dtype=x.dtype),
         mesh=mesh,
         in_specs=(P("model", dp, None), P("model", dp), P("model", dp)),
-        out_specs=P(dp, None))
+        out_specs=P(dp, None), check_vma=False)
     out = combine(yb, src, wgt)
     return out.reshape(b, t, d), aux

@@ -38,7 +38,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.sharding import context
 
 __all__ = ["make_mesh", "make_mesh2d", "replica_submesh", "shard_forward"]
@@ -145,8 +144,8 @@ def shard_forward(fwd: Callable, spec,
     out_specs = (P("data"), lfsr_spec)
     if cache_out:
         out_specs = out_specs + (P("data"),)
-    sharded = compat.shard_map(fwd, mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+    sharded = jax.shard_map(fwd, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
 
     def dispatch(params, pts, lfsr, *extra):
         with context.use_mesh(mesh):

@@ -21,7 +21,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.quant import stochastic_round_int8
 
 
@@ -39,7 +38,7 @@ def make_compressed_psum(axis_names: Tuple[str, ...]):
         err_leaves = treedef.flatten_up_to(errs)
         n = 1
         for ax in axis_names:
-            n = n * compat.axis_size(ax)
+            n = n * jax.lax.axis_size(ax)
         keys = jax.random.split(key, len(leaves))
         outs, new_errs = [], []
         for i, (g, e) in enumerate(zip(leaves, err_leaves)):

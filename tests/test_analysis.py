@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.analysis import (CODES, AnalysisWarning, Finding, dedupe,
                             enforce, error_codes, finding)
 from repro.analysis import contracts as C
@@ -139,6 +138,54 @@ class TestSpecPasses:
     ])
     def test_known_bad_shape_yields_code(self, over, code):
         assert code in codes(analyze_spec(tiny_spec(**over)))
+
+    @pytest.mark.parametrize("over,code", [
+        (dict(fused_group="grouped_transfer"), "RPA016"),
+        (dict(backend="pallas_interpret"), "RPA017"),
+        (dict(stage_backend=("ref", "pallas_interpret", "ref", "ref")),
+         "RPA017"),
+        (dict(precision="int8", backend="pallas_interpret"), "RPA017"),
+    ])
+    def test_tpu_refuses_what_would_not_compile(self, monkeypatch, over,
+                                                code):
+        """On a TPU, lowering refuses the fused gather kernel Mosaic
+        rejects and any interpret-mode backend; on the CPU the same
+        specs stay clean (the interpret-mode canaries)."""
+        from repro.api import plan as SP
+        from repro.kernels import tuning
+        spec = tiny_spec(**over)
+        assert code not in codes(analyze_spec(spec, scopes=("lowering",)))
+        monkeypatch.setattr(tuning, "on_tpu", lambda: True)
+        assert codes(analyze_spec(spec, scopes=("lowering",))) == [code]
+        with pytest.raises(ValueError, match=code):
+            SP.lower(spec, spec.to_model_config())
+
+    def test_tpu_keeps_compiled_backends(self, monkeypatch):
+        from repro.kernels import tuning
+        monkeypatch.setattr(tuning, "on_tpu", lambda: True)
+        for spec in (tiny_spec(backend="pallas"),
+                     tiny_spec(precision="int8", backend="pallas")):
+            assert analyze_spec(spec, scopes=("lowering",)) == []
+
+    @pytest.mark.parametrize("tpu,interpret,want", [
+        (False, None, True), (False, True, True), (False, False, False),
+        (True, None, False), (True, False, False)])
+    def test_resolve_interpret(self, monkeypatch, tpu, interpret, want):
+        from repro.kernels import tuning
+        monkeypatch.setattr(tuning, "on_tpu", lambda: tpu)
+        assert tuning.resolve_interpret(interpret) is want
+
+    def test_interpret_kernel_call_refused_on_tpu(self, monkeypatch):
+        """A direct kernel call asking for the interpreter raises on a
+        TPU instead of interpreting on the chip."""
+        from repro.kernels import tuning
+        from repro.kernels.knn import knn_pallas
+        monkeypatch.setattr(tuning, "on_tpu", lambda: True)
+        with pytest.raises(ValueError, match="RPA017"):
+            tuning.resolve_interpret(True)
+        pts = jnp.zeros((24, 3))
+        with pytest.raises(ValueError, match="RPA017"):
+            knn_pallas(pts, pts, 5, tile_s=8, interpret=True)
 
     def test_int8_pallas_analyzes_clean(self):
         # RPA101 retired: int8 x pallas lowers to the int8 Pallas
@@ -361,8 +408,7 @@ class TestTracePass:
         assert found == []
 
     def test_f64_caught(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             found = T.trace_callable(
                 lambda x: x.astype(jnp.float64) * 2.0, _sds((4,)),
                 where="f64")
@@ -371,8 +417,9 @@ class TestTracePass:
     def test_data_axis_collective_caught(self):
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        body = compat.shard_map(lambda x: jax.lax.psum(x, "data"), mesh,
-                                in_specs=(P("data"),), out_specs=P())
+        body = jax.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
+                             in_specs=(P("data"),), out_specs=P(),
+                             check_vma=False)
         assert "RPA204" in codes(
             T.trace_callable(body, _sds((2, 4)), where="psum"))
 

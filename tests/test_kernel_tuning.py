@@ -21,6 +21,7 @@ Four layers under test, mirroring the PR:
   explicit env wins, ``apply()`` is idempotent, unknown keys raise.
 """
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -527,6 +528,40 @@ class TestLaunchProfiles:
             assert "LD_PRELOAD" not in env
         else:                                # pragma: no cover
             assert env["LD_PRELOAD"] == TCMALLOC
+
+    def test_compile_cache_env_var_wins(self, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and
+        the helper sets no other directory."""
+        from repro.launch import profile
+        before = jax.config.jax_compilation_cache_dir
+        min_s = jax.config.jax_persistent_cache_min_compile_time_secs
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        try:
+            assert profile.configure_compile_cache() == "/elsewhere/cache"
+            assert jax.config.jax_compilation_cache_dir == before
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        finally:
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              min_s)
+
+    def test_compile_cache_defaults_to_fixed_checkout_path(self,
+                                                           monkeypatch):
+        from repro.launch import profile
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        min_s = jax.config.jax_persistent_cache_min_compile_time_secs
+        try:
+            first = profile.configure_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == first
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+            assert profile.configure_compile_cache() == first
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              min_s)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(first) == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text()
 
     def test_resolution_and_unknown_key(self):
         from repro.launch.profile import launch_profile

@@ -100,16 +100,15 @@ class TestGradCompress:
         the true gradient sum (bias -> 0)."""
         mesh = jax.make_mesh((1,), ("data",))
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         psum8 = GC.make_compressed_psum(("data",))
         g = {"w": jax.random.normal(KEY, (64, 64)) * 0.01}
         err = GC.init_error_state(g)
         total_true = jnp.zeros((64, 64))
         total_comp = jnp.zeros((64, 64))
 
-        fn = compat.shard_map(lambda gg, ee, kk: psum8(gg, ee, kk[0]),
-                              mesh=mesh, in_specs=(P(), P(), P("data")),
-                              out_specs=P())
+        fn = jax.shard_map(lambda gg, ee, kk: psum8(gg, ee, kk[0]),
+                           mesh=mesh, in_specs=(P(), P(), P("data")),
+                           out_specs=P(), check_vma=False)
         for s in range(50):
             key = jax.random.fold_in(KEY, s)
             gs = {"w": g["w"] + 0.001 * jax.random.normal(key, (64, 64))}
