@@ -145,9 +145,10 @@ def neighbor_index(new_xyz: jnp.ndarray, xyz: jnp.ndarray, k: int,
     This is the expensive, geometry-only piece the streaming cache
     (``repro.serve.streaming``) reuses across coherent LiDAR frames.
     """
-    if radius is None:
-        return knn_batched(new_xyz, xyz, k)
-    return ball_query_batched(new_xyz, xyz, k, radius)
+    with jax.named_scope("knn"):
+        if radius is None:
+            return knn_batched(new_xyz, xyz, k)
+        return ball_query_batched(new_xyz, xyz, k, radius)
 
 
 def group_with_idx(xyz: jnp.ndarray, feats: jnp.ndarray,

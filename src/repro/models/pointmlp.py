@@ -259,6 +259,27 @@ def _forward(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
     return logits, new_params, lfsr_state
 
 
+#: Kind of each plan op in its device-trace scope (:func:`op_scope`).
+_SCOPE_KIND = {
+    stage_plan.EmbedOp: "embed", stage_plan.SampleOp: "sample",
+    stage_plan.GroupOp: "group", stage_plan.FusedGroupTransferOp: "group",
+    stage_plan.CBROp: "transfer", stage_plan.ResBlockOp: "res",
+    stage_plan.PoolOp: "pool", stage_plan.HeadOp: "head",
+    stage_plan.SegHeadOp: "seghead",
+}
+
+
+def op_scope(op) -> str:
+    """The ``jax.named_scope`` a plan op runs under: its kind, then its
+    stage where it has one (``sample1``, ``group1``, ``res1``, ``head``).
+    Scopes are HLO metadata (``op_name``) only, so a device trace can
+    tell the plan ops apart; the group op's neighbour search runs under
+    a nested ``knn`` scope (``core/knn.py``)."""
+    stage = getattr(op, "stage", None)
+    return (_SCOPE_KIND.get(type(op), "op")
+            + ("" if stage is None else str(stage)))
+
+
 def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
                   lfsr_state: Optional[jnp.ndarray], train: bool, *,
                   sampler, grouper, backend,
@@ -296,98 +317,100 @@ def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
     embed_feats = None
     logits = None
     for op in plan.ops:
-        if isinstance(op, stage_plan.EmbedOp):
-            cur, new_params["embed"] = run_cbr(op.cbr, params["embed"], xyz)
-            embed_feats = cur
-        elif isinstance(op, stage_plan.SampleOp):
-            replay = (op.cached and mapping_cache is not None
-                      and not getattr(sampler, "advances_state", True))
-            if replay:
-                idx = mapping_cache["sample"][op.stage]
-            else:
-                idx, lfsr_state = sampler(cur_xyz, op.n_samples, lfsr_state,
-                                          shared_urs)
-            if collect_cache:
-                collected_sample.append(idx)
-        elif isinstance(op, stage_plan.GroupOp):
-            affine = params["stages"][op.stage].get("affine")
-            if op.cached and (mapping_cache is not None or collect_cache):
-                # Split lowering: the mapping half (neighbor_index) is
-                # replayed or collected; the arithmetic half always
-                # recomputes on the frame's features.  group_points ==
-                # group_with_idx(neighbor_index(..)) bit-for-bit.
-                new_xyz = jnp.take_along_axis(cur_xyz, idx[..., None],
-                                              axis=1)
-                if mapping_cache is not None:
-                    nbr = mapping_cache["nbr"][op.stage]
+        with jax.named_scope(op_scope(op)):
+            if isinstance(op, stage_plan.EmbedOp):
+                cur, new_params["embed"] = run_cbr(op.cbr, params["embed"], xyz)
+                embed_feats = cur
+            elif isinstance(op, stage_plan.SampleOp):
+                replay = (op.cached and mapping_cache is not None
+                          and not getattr(sampler, "advances_state", True))
+                if replay:
+                    idx = mapping_cache["sample"][op.stage]
                 else:
-                    nbr = grouper.neighbor_index(new_xyz, cur_xyz, op.k)
+                    idx, lfsr_state = sampler(cur_xyz, op.n_samples, lfsr_state,
+                                              shared_urs)
                 if collect_cache:
-                    collected_nbr.append(nbr)
-                cur_xyz, _, cur = grouper.group_with_idx(
-                    cur_xyz, cur, idx, nbr, affine, cfg.affine_mode,
-                    per_sample_norm)
+                    collected_sample.append(idx)
+            elif isinstance(op, stage_plan.GroupOp):
+                affine = params["stages"][op.stage].get("affine")
+                if op.cached and (mapping_cache is not None or collect_cache):
+                    # Split lowering: the mapping half (neighbor_index) is
+                    # replayed or collected; the arithmetic half always
+                    # recomputes on the frame's features.  group_points ==
+                    # group_with_idx(neighbor_index(..)) bit-for-bit.
+                    new_xyz = jnp.take_along_axis(cur_xyz, idx[..., None],
+                                                  axis=1)
+                    if mapping_cache is not None:
+                        nbr = mapping_cache["nbr"][op.stage]
+                    else:
+                        nbr = grouper.neighbor_index(new_xyz, cur_xyz, op.k)
+                    if collect_cache:
+                        collected_nbr.append(nbr)
+                    cur_xyz, _, cur = grouper.group_with_idx(
+                        cur_xyz, cur, idx, nbr, affine, cfg.affine_mode,
+                        per_sample_norm)
+                else:
+                    cur_xyz, _, cur = grouper(cur_xyz, cur, idx, op.k, affine,
+                                              cfg.affine_mode, per_sample_norm)
+            elif isinstance(op, stage_plan.CBROp):
+                # Bare CBR ops are stage transfers (embed/head CBRs ride
+                # inside their wrapper ops).
+                p = stage_plan.param_at(params, op.path)
+                cur, new_stages[op.stage]["transfer"] = run_cbr(op, p, cur)
+            elif isinstance(op, stage_plan.FusedGroupTransferOp):
+                if train:
+                    raise ValueError(
+                        "fused group->transfer ops are inference-only; "
+                        "train with fused_group='none'")
+                affine = params["stages"][op.stage].get("affine")
+                p = stage_plan.param_at(params, op.cbr.path)
+                cur_xyz, _, cur = op.fn(p, cur_xyz, cur, idx, op.k, affine,
+                                        cfg.affine_mode, per_sample_norm,
+                                        act=op.cbr.act)
+            elif isinstance(op, stage_plan.ResBlockOp):
+                blk = params["stages"][op.stage][op.branch][op.index]
+                h, n1 = run_cbr(op.net1, blk["net1"], cur)
+                h, n2 = run_cbr(op.net2, blk["net2"], h)
+                cur = jax.nn.relu(h + cur)
+                new_stages[op.stage][op.branch].append({"net1": n1, "net2": n2})
+            elif isinstance(op, stage_plan.PoolOp):
+                cur = jnp.max(cur, axis=op.axis)
+            elif isinstance(op, stage_plan.HeadOp):
+                head = params["head"]
+                h, f1 = run_cbr(op.fc1, head["fc1"], cur)
+                h, f2 = run_cbr(op.fc2, head["fc2"], h)
+                fc3_quant = ((cfg.quant if cfg.quant.enabled else None)
+                             if train else op.fc3_quant)
+                logits = L.conv1d_apply(head["fc3"], h, quant=fc3_quant)
+                new_params["head"] = {"fc1": f1, "fc2": f2, "fc3": head["fc3"]}
+            elif isinstance(op, stage_plan.SegHeadOp):
+                # Per-point segmentation head: global descriptor pooled
+                # here (no standalone global PoolOp in seg plans), final
+                # stage features upsampled back to input resolution by
+                # 1-NN (the cacheable mapping op), skip concat, 3-layer
+                # per-point classifier -> [B, n_points, n_classes].
+                g = jnp.max(cur, axis=1)                           # [B, C4]
+                replay = op.cached and mapping_cache is not None
+                if replay:
+                    up_idx = mapping_cache["up"]
+                else:
+                    with jax.named_scope("knn"):
+                        up_idx = knn_core.knn_batched(xyz, cur_xyz, 1)
+                if collect_cache:
+                    collected_up = up_idx
+                upsampled = knn_core.gather_neighbors(cur, up_idx)[:, :, 0, :]
+                g_b = jnp.broadcast_to(g[:, None, :],
+                                       upsampled.shape[:2] + (g.shape[-1],))
+                h = jnp.concatenate([embed_feats, upsampled, g_b], axis=-1)
+                head = params["head"]
+                h, f1 = run_cbr(op.fc1, head["fc1"], h)
+                h, f2 = run_cbr(op.fc2, head["fc2"], h)
+                fc3_quant = ((cfg.quant if cfg.quant.enabled else None)
+                             if train else op.fc3_quant)
+                logits = L.conv1d_apply(head["fc3"], h, quant=fc3_quant)
+                new_params["head"] = {"fc1": f1, "fc2": f2, "fc3": head["fc3"]}
             else:
-                cur_xyz, _, cur = grouper(cur_xyz, cur, idx, op.k, affine,
-                                          cfg.affine_mode, per_sample_norm)
-        elif isinstance(op, stage_plan.CBROp):
-            # Bare CBR ops are stage transfers (embed/head CBRs ride
-            # inside their wrapper ops).
-            p = stage_plan.param_at(params, op.path)
-            cur, new_stages[op.stage]["transfer"] = run_cbr(op, p, cur)
-        elif isinstance(op, stage_plan.FusedGroupTransferOp):
-            if train:
-                raise ValueError(
-                    "fused group->transfer ops are inference-only; "
-                    "train with fused_group='none'")
-            affine = params["stages"][op.stage].get("affine")
-            p = stage_plan.param_at(params, op.cbr.path)
-            cur_xyz, _, cur = op.fn(p, cur_xyz, cur, idx, op.k, affine,
-                                    cfg.affine_mode, per_sample_norm,
-                                    act=op.cbr.act)
-        elif isinstance(op, stage_plan.ResBlockOp):
-            blk = params["stages"][op.stage][op.branch][op.index]
-            h, n1 = run_cbr(op.net1, blk["net1"], cur)
-            h, n2 = run_cbr(op.net2, blk["net2"], h)
-            cur = jax.nn.relu(h + cur)
-            new_stages[op.stage][op.branch].append({"net1": n1, "net2": n2})
-        elif isinstance(op, stage_plan.PoolOp):
-            cur = jnp.max(cur, axis=op.axis)
-        elif isinstance(op, stage_plan.HeadOp):
-            head = params["head"]
-            h, f1 = run_cbr(op.fc1, head["fc1"], cur)
-            h, f2 = run_cbr(op.fc2, head["fc2"], h)
-            fc3_quant = ((cfg.quant if cfg.quant.enabled else None)
-                         if train else op.fc3_quant)
-            logits = L.conv1d_apply(head["fc3"], h, quant=fc3_quant)
-            new_params["head"] = {"fc1": f1, "fc2": f2, "fc3": head["fc3"]}
-        elif isinstance(op, stage_plan.SegHeadOp):
-            # Per-point segmentation head: global descriptor pooled
-            # here (no standalone global PoolOp in seg plans), final
-            # stage features upsampled back to input resolution by
-            # 1-NN (the cacheable mapping op), skip concat, 3-layer
-            # per-point classifier -> [B, n_points, n_classes].
-            g = jnp.max(cur, axis=1)                           # [B, C4]
-            replay = op.cached and mapping_cache is not None
-            if replay:
-                up_idx = mapping_cache["up"]
-            else:
-                up_idx = knn_core.knn_batched(xyz, cur_xyz, 1)  # [B,N,1]
-            if collect_cache:
-                collected_up = up_idx
-            upsampled = knn_core.gather_neighbors(cur, up_idx)[:, :, 0, :]
-            g_b = jnp.broadcast_to(g[:, None, :],
-                                   upsampled.shape[:2] + (g.shape[-1],))
-            h = jnp.concatenate([embed_feats, upsampled, g_b], axis=-1)
-            head = params["head"]
-            h, f1 = run_cbr(op.fc1, head["fc1"], h)
-            h, f2 = run_cbr(op.fc2, head["fc2"], h)
-            fc3_quant = ((cfg.quant if cfg.quant.enabled else None)
-                         if train else op.fc3_quant)
-            logits = L.conv1d_apply(head["fc3"], h, quant=fc3_quant)
-            new_params["head"] = {"fc1": f1, "fc2": f2, "fc3": head["fc3"]}
-        else:
-            raise TypeError(f"unknown stage-plan op {type(op).__name__}")
+                raise TypeError(f"unknown stage-plan op {type(op).__name__}")
     new_params["stages"] = new_stages
     cache = None
     if collect_cache:

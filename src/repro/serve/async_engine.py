@@ -26,6 +26,19 @@ serving for free):
   flight; its futures resolve when the next dispatch is enqueued, on an
   idle ``pump()``, or at ``flush()``.
 
+Spans and counters
+------------------
+Each dispatch runs four host regions, each under a
+``jax.profiler.TraceAnnotation`` (on the profiler's clock, beside the
+device planes) and a ``PointCloudStats`` timer on
+``time.perf_counter()``: ``serve.stage`` / ``host_s`` (queue pop,
+stack, pad), ``serve.enqueue`` / ``enqueue_s`` (the asynchronous
+``pipeline.infer*`` call), ``serve.wait`` / ``wait_s`` (block until the
+device finishes) and ``serve.resolve`` / ``resolve_s`` (split rows,
+resolve futures, run their done-callbacks, refresh stream caches).  All
+four spans of one dispatch carry its sequence number as ``dispatch``.
+A ``pump()`` that neither dispatches nor retires opens no span.
+
 LFSR contract (and why it differs from the sync engine)
 -------------------------------------------------------
 Every dispatch starts from the engine's *seed* LFSR state instead of
@@ -163,6 +176,7 @@ class _Inflight:
     """One dispatched batch whose device compute may still be running."""
     futures: List[ServeFuture]
     logits: jnp.ndarray          # [max_batch, ...], device-async
+    seq: int                     # dispatch sequence number (span id)
     # Per-future stream info, parallel to ``futures`` (None for plain
     # requests): ("hit", state, cache_rows) | ("miss", state, cloud).
     stream: List = dataclasses.field(default_factory=list)
@@ -237,14 +251,15 @@ class AsyncPointCloudEngine:
                              f"int, got {calibrate_every!r}")
         self.calibrate_every = calibrate_every
         # Sliding-window origin for the periodic recalibration: the
-        # (batches, serve_s) reading at the last calibration.
-        self._cal_origin = (0, 0.0)
+        # (batches, enqueue_s, wait_s) reading at the last calibration.
+        self._cal_origin = (0, 0.0, 0.0)
         # One stream per dispatch lane, sized from max_batch (the old
         # 64-stream floor under-provisioned max_batch > 64).
         self._lfsr0 = pipeline.seed_state(seed, self.max_batch)
         self._queue: collections.deque = collections.deque()
         self._inflight: Optional[_Inflight] = None
         self._seq = 0
+        self._dispatches = 0
         self._closed = False
 
     @classmethod
@@ -350,7 +365,7 @@ class AsyncPointCloudEngine:
         recalibration window origin resets with it."""
         self.stats.reset()
         self.latencies_ms.clear()
-        self._cal_origin = (0, 0.0)
+        self._cal_origin = (0, 0.0, 0.0)
 
     def calibrate_policy(self) -> bool:
         """Force-refresh a calibratable policy (``POLICIES["cost"]``)
@@ -370,7 +385,7 @@ class AsyncPointCloudEngine:
             return False
         calibrate(self.stats, self.max_batch,
                   data_shards=self.spec.data_shards)
-        self._cal_origin = (self.stats.batches, self.stats.serve_s)
+        self._cal_origin = self._cal_reading()
         return True
 
     def _maybe_recalibrate(self) -> None:
@@ -384,16 +399,20 @@ class AsyncPointCloudEngine:
         calibrate = getattr(self.policy, "calibrate", None)
         if calibrate is None:
             return
-        batches0, serve_s0 = self._cal_origin
+        batches0, enqueue_s0, wait_s0 = self._cal_origin
         window_batches = self.stats.batches - batches0
         if window_batches < self.calibrate_every:
             return
-        window = PointCloudStats()
-        window.batches = window_batches
-        window.serve_s = self.stats.serve_s - serve_s0
+        window = PointCloudStats(batches=window_batches,
+                                 enqueue_s=self.stats.enqueue_s - enqueue_s0,
+                                 wait_s=self.stats.wait_s - wait_s0)
         calibrate(window, self.max_batch,
                   data_shards=self.spec.data_shards)
-        self._cal_origin = (self.stats.batches, self.stats.serve_s)
+        self._cal_origin = self._cal_reading()
+
+    def _cal_reading(self):
+        return (self.stats.batches, self.stats.enqueue_s,
+                self.stats.wait_s)
 
     def warmup(self) -> float:
         """Compile the one ``(max_batch, n_points)`` executable ahead of
@@ -401,7 +420,7 @@ class AsyncPointCloudEngine:
         restart from the seed state anyway).  Returns compile seconds."""
         dummy = jnp.zeros((self.max_batch, self.cfg.n_points, 3),
                           jnp.float32)
-        t0 = time.time()
+        t0 = time.perf_counter()
         if self.pipeline.streaming:
             # Streaming dispatches run the collect/cached executables,
             # not the plain one — compile both.
@@ -413,7 +432,7 @@ class AsyncPointCloudEngine:
         else:
             logits, _ = self.pipeline.infer(dummy, jnp.array(self._lfsr0))
             jax.block_until_ready(logits)
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         self.stats.compile_s += dt
         return dt
 
@@ -425,7 +444,48 @@ class AsyncPointCloudEngine:
     # ------------------------------------------------ dispatch core ----
 
     def _dispatch(self, n: int) -> None:
-        t_host = time.time()
+        seq = self._dispatches
+        self._dispatches += 1
+        t_stage = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.stage", dispatch=seq):
+            taken, batch, pad, stream, cache_in = self._stage(n)
+        t_enqueue = time.perf_counter()
+        self.stats.host_s += t_enqueue - t_stage
+
+        # Enqueue batch N+1 on the device, *then* retire batch N: the
+        # block on N overlaps with N+1's H2D transfer + compute, and the
+        # stack/pad above overlapped with N's compute.  The returned
+        # LFSR state is discarded — every dispatch restarts from the
+        # seed state (dispatch-invariance contract; for streams this is
+        # what makes a cached frame bit-identical to its cold replay).
+        with jax.profiler.TraceAnnotation("serve.enqueue", dispatch=seq):
+            cache_out = None
+            if cache_in is not None:
+                logits, _ = self.pipeline.infer_cached(
+                    batch, jnp.array(self._lfsr0), cache_in)
+            elif self.pipeline.streaming:
+                # Collect-path logits are bit-identical to plain infer,
+                # so plain requests keep golden equivalence; only miss
+                # sessions read their cache row back at retire time.
+                logits, _, cache_out = self.pipeline.infer_collect(
+                    batch, jnp.array(self._lfsr0))
+            else:
+                logits, _ = self.pipeline.infer(batch,
+                                                jnp.array(self._lfsr0))
+        self.stats.enqueue_s += time.perf_counter() - t_enqueue
+        nxt = _Inflight([f for _, f, _ in taken], logits, seq, stream,
+                        cache_out)
+        self._retire()
+        self._inflight = nxt
+        self.stats.batches += 1
+        self.stats.padded += pad
+        self.stats.requests += len(taken)
+
+    def _stage(self, n: int):
+        """Pop up to ``n`` queued requests and build the dispatch's host
+        inputs: ``(taken, batch, pad, stream, cache_in)``, where
+        ``cache_in`` holds the stacked cache rows of a cache-replay
+        dispatch (None otherwise)."""
         streaming = self.pipeline.streaming
         if streaming:
             # Homogeneous-prefix run: one dispatch is either a
@@ -442,13 +502,14 @@ class AsyncPointCloudEngine:
                 run += 1
             n = run
         taken = [self._queue.popleft() for _ in range(n)]
+        now = self._clock()
+        self.stats.queued_s += sum(now - f.t_submit for _, f, _ in taken)
         chunk = batching.stack_requests([c for c, _, _ in taken],
                                         self.cfg.n_points)
         batch, pad = batching.pad_to_batch(chunk, self.max_batch)
         stream = [s for _, _, s in taken]
-        hit_run = streaming and stream[0] is not None \
-            and stream[0][0] == "hit"
-        if hit_run:
+        cache_in = None
+        if streaming and stream[0] is not None and stream[0][0] == "hit":
             # Stack the sessions' per-lane cache rows; pad lanes replay
             # zero indices (index 0 everywhere — valid, computed, never
             # returned, exactly like zero-padded clouds).
@@ -457,56 +518,36 @@ class AsyncPointCloudEngine:
                      ] * pad
             cache_in = jax.tree_util.tree_map(
                 lambda *r: jnp.stack(r), *rows)
-        self.stats.host_s += time.time() - t_host
-
-        # Enqueue batch N+1 on the device, *then* retire batch N: the
-        # block on N overlaps with N+1's H2D transfer + compute, and the
-        # stack/pad above overlapped with N's compute.  The returned
-        # LFSR state is discarded — every dispatch restarts from the
-        # seed state (dispatch-invariance contract; for streams this is
-        # what makes a cached frame bit-identical to its cold replay).
-        t0 = time.time()
-        cache_out = None
-        if hit_run:
-            logits, _ = self.pipeline.infer_cached(
-                batch, jnp.array(self._lfsr0), cache_in)
-        elif streaming:
-            # Collect-path logits are bit-identical to plain infer, so
-            # plain requests keep golden equivalence; only miss
-            # sessions read their cache row back at retire time.
-            logits, _, cache_out = self.pipeline.infer_collect(
-                batch, jnp.array(self._lfsr0))
-        else:
-            logits, _ = self.pipeline.infer(batch, jnp.array(self._lfsr0))
-        self.stats.serve_s += time.time() - t0
-        nxt = _Inflight([f for _, f, _ in taken], logits, stream,
-                        cache_out)
-        self._retire()
-        self._inflight = nxt
-        self.stats.batches += 1
-        self.stats.padded += pad
-        self.stats.requests += n
+        return taken, batch, pad, stream, cache_in
 
     def _retire(self, wait: bool = True) -> None:
         if self._inflight is None:
             return
         if not wait and not _is_ready(self._inflight.logits):
             return                       # device still busy; try later
-        t0 = time.time()
-        logits = jax.block_until_ready(self._inflight.logits)
-        self.stats.serve_s += time.time() - t0
+        t_wait = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.wait",
+                                          dispatch=self._inflight.seq):
+            logits = jax.block_until_ready(self._inflight.logits)
+        t_resolve = time.perf_counter()
+        self.stats.wait_s += t_resolve - t_wait
         inflight, self._inflight = self._inflight, None
-        now = self._clock()
-        for i, fut in enumerate(inflight.futures):
-            fut._resolve(logits[i], now)
-            self.latencies_ms.append(fut.latency_ms)
-            info = inflight.stream[i] if i < len(inflight.stream) else None
-            if (info is not None and info[0] == "miss"
-                    and inflight.cache is not None):
-                _, state, cloud = info
-                state.refresh(
-                    jax.tree_util.tree_map(lambda a, i=i: a[i],
-                                           inflight.cache), cloud)
+        with jax.profiler.TraceAnnotation("serve.resolve",
+                                          dispatch=inflight.seq):
+            now = self._clock()
+            for i, fut in enumerate(inflight.futures):
+                fut._resolve(logits[i], now)
+                self.latencies_ms.append(fut.latency_ms)
+                info = (inflight.stream[i] if i < len(inflight.stream)
+                        else None)
+                if (info is not None and info[0] == "miss"
+                        and inflight.cache is not None):
+                    _, state, cloud = info
+                    state.refresh(
+                        jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                               inflight.cache), cloud)
+        self.stats.resolve_s += time.perf_counter() - t_resolve
+        self.stats.retired += 1
 
     # ------------------------------------------------ asyncio shell ----
 

@@ -25,18 +25,39 @@ import numpy as np
 
 @dataclasses.dataclass
 class PointCloudStats:
-    """The serving-stats schema shared by the sync and async engines."""
+    """The serving-stats schema shared by the sync and async engines.
+
+    The timers are host seconds on ``time.perf_counter()``, one timer per
+    region of a dispatch, so they never overlap: ``host_s`` (stage the
+    batch), ``enqueue_s`` (the asynchronous ``pipeline.infer*`` call),
+    ``wait_s`` (block until the device finishes), ``resolve_s`` (split
+    the batch into rows, resolve the futures and run their
+    done-callbacks).  ``retired``, ``resolve_s`` and ``queued_s`` are the
+    async engine's; the sync engine returns its rows from ``classify``.
+    """
     requests: int = 0          # real samples served
     batches: int = 0           # jitted fixed-shape dispatches
+    retired: int = 0           # dispatches whose futures were resolved
     padded: int = 0            # dummy pad samples computed
     compile_s: float = 0.0     # time spent in warmup compiles
-    serve_s: float = 0.0       # device time in the jitted dispatch loop
-    host_s: float = 0.0        # host-side padding / array conversion
+    host_s: float = 0.0        # stage: queue pop, stack, pad-to-batch
+    enqueue_s: float = 0.0     # the pipeline.infer* call (async enqueue)
+    wait_s: float = 0.0        # block_until_ready on the dispatch
+    resolve_s: float = 0.0     # rows split out, futures and callbacks
+    queued_s: float = 0.0      # sum of (dispatch - submit), engine clock
+
+    @property
+    def serve_s(self) -> float:
+        """Host seconds spent in the enqueue call and blocked on the
+        device: ``enqueue_s + wait_s`` (staging and resolving excluded).
+        The cost policy calibrates its per-dispatch service time from
+        it."""
+        return self.enqueue_s + self.wait_s
 
     @property
     def samples_per_s(self) -> float:
-        """Device throughput: host-side queue prep (array conversion,
-        pad-to-batch) is tracked separately in ``host_s``."""
+        """Requests over ``serve_s``, on the host clock: host-side queue
+        prep (``host_s``) and resolving (``resolve_s``) are left out."""
         return self.requests / max(self.serve_s, 1e-9)
 
     def reset(self) -> None:

@@ -92,10 +92,10 @@ class PointCloudEngine:
         consume LFSR state).  Returns compile seconds."""
         b = self.max_batch
         dummy = jnp.zeros((b, self.cfg.n_points, 3), jnp.float32)
-        t0 = time.time()
+        t0 = time.perf_counter()
         logits, _ = self.pipeline.infer(dummy, jnp.array(self._lfsr))
         logits.block_until_ready()
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         self.stats.compile_s += dt
         return dt
 
@@ -123,10 +123,11 @@ class PointCloudEngine:
         Returns: logits [R, n_classes] — rows only for the R real
         requests; pad lanes are computed but never returned.
 
-        ``stats.serve_s`` times only the jitted dispatch loop (device
-        work); padding/conversion lands in ``stats.host_s``.
+        ``stats.enqueue_s`` times the dispatch loop's enqueue calls and
+        ``stats.wait_s`` the block on the last one (together
+        ``stats.serve_s``); padding/conversion lands in ``stats.host_s``.
         """
-        t_host = time.time()
+        t_host = time.perf_counter()
         pts = batching.as_point_queue(points, self.cfg.n_points)
         if pts.shape[0] == 0:                       # drained queue
             if self.cfg.head == "seg":
@@ -136,16 +137,18 @@ class PointCloudEngine:
             return jnp.zeros((0, self.cfg.n_classes), jnp.float32)
         r = pts.shape[0]
         chunks = self._chunk_queue(pts)
-        self.stats.host_s += time.time() - t_host
+        self.stats.host_s += time.perf_counter() - t_host
 
-        t0 = time.time()
+        t_enqueue = time.perf_counter()
         out = []
         for j, chunk in enumerate(chunks):
             logits, self._lfsr = self.pipeline.infer(chunk, self._lfsr)
             out.append(logits[:min(self.max_batch, r - j * self.max_batch)])
             self.stats.batches += 1
+        t_wait = time.perf_counter()
+        self.stats.enqueue_s += t_wait - t_enqueue
         jax.block_until_ready(out[-1])
-        self.stats.serve_s += time.time() - t0
+        self.stats.wait_s += time.perf_counter() - t_wait
         self.stats.requests += r
         return jnp.concatenate(out, axis=0)
 

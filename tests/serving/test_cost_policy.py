@@ -17,9 +17,7 @@ from repro.serve.policy import POLICIES, CostModelBatch, make_policy
 
 
 def window(serve_s: float, batches: int) -> PointCloudStats:
-    s = PointCloudStats()
-    s.serve_s, s.batches = serve_s, batches
-    return s
+    return PointCloudStats(batches=batches, enqueue_s=serve_s)
 
 
 class TestCalibration:

@@ -2,8 +2,9 @@
 
 ``PointCloudStats`` is the one schema both engines report
 (``repro.serve.batching``): counters (requests/batches/padded), timers
-(compile_s/serve_s/host_s, disjoint by construction), the derived
-``samples_per_s``, and ``reset()`` as a fresh measurement window.
+(compile_s/host_s/enqueue_s/wait_s/resolve_s, disjoint by
+construction), the derived ``serve_s`` and ``samples_per_s``, and
+``reset()`` as a fresh measurement window.
 """
 import dataclasses
 
@@ -15,7 +16,8 @@ from repro.serve.async_engine import AsyncPointCloudEngine
 from repro.serve.batching import PointCloudStats
 from repro.serve.pointcloud import PointCloudEngine
 
-FIELDS = ("requests", "batches", "padded", "compile_s", "serve_s", "host_s")
+FIELDS = ("requests", "batches", "retired", "padded", "compile_s",
+          "host_s", "enqueue_s", "wait_s", "resolve_s", "queued_s")
 
 
 @pytest.fixture()
