@@ -19,12 +19,19 @@ serving for free):
   fixed-shape dispatch now.
 * **Double-buffered dispatch** — ``pipeline.infer`` is an asynchronous
   dispatch in JAX, so the engine enqueues batch N+1 (host-side
-  stack/pad + device transfer) *before* blocking on batch N: host prep
-  of the next batch overlaps device compute of the current one, the
-  software rendering of the stall-free deep pipelining that PointAcc /
-  Neu et al. get from hardware FIFOs.  At most one dispatch is in
-  flight; its futures resolve when the next dispatch is enqueued, on an
-  idle ``pump()``, or at ``flush()``.
+  stack/pad + device transfer) *before* retiring batch N, so batch N
+  is retired while N+1 runs: host prep of the next batch and the
+  retire of the last one overlap device compute, the software
+  rendering of the stall-free deep pipelining that PointAcc / Neu et
+  al. get from hardware FIFOs.  At most one dispatch is in flight; its
+  futures resolve when the next dispatch is enqueued, on an idle
+  ``pump()``, or at ``flush()``.
+* **One host copy per dispatch** — the dispatch's logits start their
+  device-to-host copy as soon as they are enqueued
+  (``copy_to_host_async``), so the copy waits for that batch alone,
+  never for the batch enqueued after it.  Retiring reads the one
+  ``[max_batch, ...]`` host block and resolves each future with a
+  read-only numpy view of its row: no device program runs per row.
 
 Spans and counters
 ------------------
@@ -34,8 +41,9 @@ device planes) and a ``PointCloudStats`` timer on
 ``time.perf_counter()``: ``serve.stage`` / ``host_s`` (queue pop,
 stack, pad), ``serve.enqueue`` / ``enqueue_s`` (the asynchronous
 ``pipeline.infer*`` call), ``serve.wait`` / ``wait_s`` (block until the
-device finishes) and ``serve.resolve`` / ``resolve_s`` (split rows,
-resolve futures, run their done-callbacks, refresh stream caches).  All
+device finishes, then take the dispatch's one host copy of its logits)
+and ``serve.resolve`` / ``resolve_s`` (host work only: resolve futures
+with their rows, run their done-callbacks, refresh stream caches).  All
 four spans of one dispatch carry its sequence number as ``dispatch``.
 A ``pump()`` that neither dispatches nor retires opens no span.
 
@@ -106,9 +114,11 @@ class ServeFuture:
     """Completion handle for one submitted cloud.
 
     Resolved by the engine (never by callers) with the request's
-    ``[n_classes]`` logits row.  ``t_submit`` / ``t_done`` are stamped
-    from the engine's clock — wall time in production, virtual time
-    under the test harness — so ``latency_ms`` is exact either way.
+    logits row (``[n_classes]``, or ``[N, parts]`` for segmentation): a
+    read-only ``np.ndarray`` view of its dispatch's one host block.
+    ``t_submit`` / ``t_done`` are stamped from the engine's clock — wall
+    time in production, virtual time under the test harness — so
+    ``latency_ms`` is exact either way.
     """
 
     __slots__ = ("request_id", "t_submit", "t_done", "_value", "_done",
@@ -125,8 +135,9 @@ class ServeFuture:
     def done(self) -> bool:
         return self._done
 
-    def result(self) -> jnp.ndarray:
-        """The logits row; raises while pending (pump/flush the engine)."""
+    def result(self) -> np.ndarray:
+        """The logits row, a read-only host ``np.ndarray``; raises while
+        pending (pump/flush the engine)."""
         if not self._done:
             raise RuntimeError(
                 f"request {self.request_id} is still pending — drive the "
@@ -161,7 +172,7 @@ class ServeFuture:
             return None
         return (self.t_done - self.t_submit) * 1e3
 
-    def _resolve(self, value: jnp.ndarray, t_done: float) -> None:
+    def _resolve(self, value: np.ndarray, t_done: float) -> None:
         assert not self._done, "a request resolves exactly once"
         self._value = value
         self.t_done = t_done
@@ -175,7 +186,7 @@ class ServeFuture:
 class _Inflight:
     """One dispatched batch whose device compute may still be running."""
     futures: List[ServeFuture]
-    logits: jnp.ndarray          # [max_batch, ...], device-async
+    logits: jax.Array            # [max_batch, ...], copying to host
     seq: int                     # dispatch sequence number (span id)
     # Per-future stream info, parallel to ``futures`` (None for plain
     # requests): ("hit", state, cache_rows) | ("miss", state, cloud).
@@ -472,6 +483,9 @@ class AsyncPointCloudEngine:
             else:
                 logits, _ = self.pipeline.infer(batch,
                                                 jnp.array(self._lfsr0))
+            # Start the one host copy now: it waits for this batch
+            # alone, not for the batch enqueued after it.
+            logits.copy_to_host_async()
         self.stats.enqueue_s += time.perf_counter() - t_enqueue
         nxt = _Inflight([f for _, f, _ in taken], logits, seq, stream,
                         cache_out)
@@ -528,7 +542,8 @@ class AsyncPointCloudEngine:
         t_wait = time.perf_counter()
         with jax.profiler.TraceAnnotation("serve.wait",
                                           dispatch=self._inflight.seq):
-            logits = jax.block_until_ready(self._inflight.logits)
+            rows = np.asarray(jax.block_until_ready(
+                self._inflight.logits))
         t_resolve = time.perf_counter()
         self.stats.wait_s += t_resolve - t_wait
         inflight, self._inflight = self._inflight, None
@@ -536,7 +551,7 @@ class AsyncPointCloudEngine:
                                           dispatch=inflight.seq):
             now = self._clock()
             for i, fut in enumerate(inflight.futures):
-                fut._resolve(logits[i], now)
+                fut._resolve(rows[i], now)
                 self.latencies_ms.append(fut.latency_ms)
                 info = (inflight.stream[i] if i < len(inflight.stream)
                         else None)
@@ -551,8 +566,9 @@ class AsyncPointCloudEngine:
 
     # ------------------------------------------------ asyncio shell ----
 
-    async def classify_async(self, points) -> jnp.ndarray:
-        """Submit one cloud and await its logits.
+    async def classify_async(self, points) -> np.ndarray:
+        """Submit one cloud and await its logits row (a read-only host
+        ``np.ndarray``, as :meth:`ServeFuture.result` returns).
 
         Needs something pumping the engine concurrently — run
         :meth:`serve_loop` as a background task.

@@ -344,9 +344,10 @@ class PipelineFleet:
     # ------------------------------------------------ asyncio shell ----
 
     async def classify_async(self, tenant: str, points):
-        """Submit one cloud for ``tenant`` and await its logits (needs
-        :meth:`serve_loop` running).  ``Overloaded`` propagates to the
-        caller synchronously — shed is an answer, not a wait."""
+        """Submit one cloud for ``tenant`` and await its logits row, a
+        read-only host ``np.ndarray`` (needs :meth:`serve_loop`
+        running).  ``Overloaded`` propagates to the caller synchronously
+        — shed is an answer, not a wait."""
         loop = asyncio.get_running_loop()
         afut = loop.create_future()
 

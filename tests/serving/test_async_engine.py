@@ -319,6 +319,80 @@ class TestDispatchMechanics:
 
 
 # ------------------------------------------------------------------ #
+# host rows: one host copy of each dispatch's logits                 #
+# ------------------------------------------------------------------ #
+
+class TestHostRows:
+    def _dispatch_of_three(self, pipeline, clouds):
+        eng = make_engine(pipeline, VirtualClock())
+        futures = [eng.submit(c) for c in clouds[:3]]     # + 1 pad lane
+        eng.flush()
+        return eng, futures
+
+    def test_result_is_a_host_ndarray(self, tiny_pipeline, tiny_spec,
+                                      clouds):
+        _, futures = self._dispatch_of_three(tiny_pipeline, clouds)
+        for f in futures:
+            row = f.result()
+            assert type(row) is np.ndarray
+            assert row.shape == (tiny_spec.to_model_config().n_classes,)
+            assert not row.flags.writeable
+
+    def test_cobatched_rows_share_one_host_block(self, tiny_pipeline,
+                                                 clouds):
+        """The rows of one dispatch are views of one [max_batch, ...]
+        host block, in lane order: one copy, not one per row."""
+        _, futures = self._dispatch_of_three(tiny_pipeline, clouds)
+        rows = [f.result() for f in futures]
+        block = rows[0].base
+        assert isinstance(block, np.ndarray)
+        assert block.shape == (MAX_BATCH,) + rows[0].shape
+        for i, row in enumerate(rows):
+            assert row.base is block
+            assert np.shares_memory(row, block)
+            assert np.shares_memory(row, block[i])
+
+    def test_rows_bit_identical_to_direct_infer_on_padded_batch(
+            self, tiny_pipeline, clouds):
+        import jax.numpy as jnp
+
+        from repro.serve import batching
+        _, futures = self._dispatch_of_three(tiny_pipeline, clouds)
+        batch, pad = batching.pad_to_batch(
+            batching.stack_requests(list(clouds[:3]),
+                                    tiny_pipeline.spec.n_points),
+            MAX_BATCH)
+        assert pad == 1
+        want, _ = tiny_pipeline.infer(
+            batch, jnp.array(tiny_pipeline.seed_state(SEED, MAX_BATCH)))
+        want = np.asarray(want)
+        for i, f in enumerate(futures):
+            np.testing.assert_array_equal(f.result(), want[i])
+            assert f.result().tobytes() == want[i].tobytes()
+
+    def test_callback_cannot_write_its_row(self, tiny_pipeline, clouds):
+        """A done-callback that writes to its row raises; the row and
+        its co-batched neighbours keep their values."""
+        eng = make_engine(tiny_pipeline, VirtualClock())
+        futures = [eng.submit(c) for c in clouds[:3]]
+        raised = []
+
+        def scribble(fut):
+            try:
+                fut.result()[:] = 0.0
+            except ValueError as e:
+                raised.append(e)
+
+        futures[0].add_done_callback(scribble)
+        eng.flush()
+        assert len(raised) == 1 and "read-only" in str(raised[0])
+        other = make_engine(tiny_pipeline, VirtualClock())
+        ref = [other.submit(c) for c in clouds[:3]]
+        other.flush()
+        np.testing.assert_array_equal(results(futures), results(ref))
+
+
+# ------------------------------------------------------------------ #
 # policies: SLO-aware dispatch sizing on scripted traces             #
 # ------------------------------------------------------------------ #
 

@@ -76,13 +76,56 @@ class TestCounters:
 
     def test_resolve_s_times_the_done_callbacks(self, engine, clouds):
         """Client code run from a done-callback is resolve time, not
-        device wait."""
+        device wait: the wait is closed before any callback runs."""
         import time
+        seen = []
+
+        def slow_client(fut):
+            seen.append(engine.stats.wait_s)
+            time.sleep(0.05)
+
         fut = engine.submit(clouds[0])
-        fut.add_done_callback(lambda f: time.sleep(0.05))
+        fut.add_done_callback(slow_client)
         engine.flush()
+        assert len(seen) == 1
+        assert engine.stats.wait_s == seen[0]
         assert engine.stats.resolve_s >= 0.05
-        assert engine.stats.wait_s < 0.05
+
+    def test_wait_s_covers_the_host_copy(self, engine, clouds,
+                                         monkeypatch):
+        """The dispatch's one host copy of its logits is device wait:
+        a copy that takes one second of the engine's timer clock lands
+        in ``wait_s`` whole, and ``resolve_s`` holds none of it."""
+        import numpy as np
+
+        from repro.serve import async_engine
+        now = [0.0]
+        monkeypatch.setattr(async_engine, "time", types.SimpleNamespace(
+            perf_counter=lambda: now[0]))
+
+        class SlowCopy:
+            """Dispatch logits whose host copy takes one second."""
+            def __init__(self, logits):
+                self.logits = logits
+
+            def copy_to_host_async(self):
+                self.logits.copy_to_host_async()
+
+            def block_until_ready(self):
+                return self
+
+            def __array__(self, dtype=None, copy=None):
+                now[0] += 1.0
+                return np.asarray(self.logits)
+
+        infer = engine.pipeline.infer
+        engine.pipeline = types.SimpleNamespace(
+            streaming=False,
+            infer=lambda b, s: (SlowCopy(infer(b, s)[0]), None))
+        fut = engine.submit(clouds[0])
+        engine.flush()
+        assert fut.done()
+        assert (engine.stats.wait_s, engine.stats.resolve_s) == (1.0, 0.0)
 
 
 def _serve_spans(logdir):
