@@ -6,10 +6,18 @@ configuration file (``configs/<config>.json``) under a traffic file
 (``traffic/<traffic>.json``).  Nothing here names a cell; a new cell is
 new data files and a new entry.
 
+Nothing here names a model either.  A configuration's ``reference`` key
+names a module under this directory that provides what is particular
+to its model (:data:`CONTRACT`; ``reference.py`` documents it): the
+traffic's inputs, the plain reference's weights and forward, the
+decision paths that float32 rounding leaves open, and the work of one
+input.  A new architecture is a new configuration file, reference
+module and traffic file.
+
 The window drives the served path itself: ``AsyncPointCloudEngine``
 ``submit``/``pump`` (and its stream sessions) or ``PipelineFleet``
 ``submit``/``pump``.  Every request carries the time it was due; its
-latency runs from then until its logits are on the host.
+latency runs from then until its answer is on the host.
 """
 from __future__ import annotations
 
@@ -17,11 +25,12 @@ import collections
 import contextlib
 import dataclasses
 import gc
+import importlib.util
 import json
 import pathlib
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -32,9 +41,12 @@ sys.path.insert(0, str(HERE))
 
 import jax  # noqa: E402
 
-import decisions  # noqa: E402
-import reference  # noqa: E402
+import work  # noqa: E402
 from traffic import clouds, schedule  # noqa: E402
+
+#: What a configuration's reference module provides.
+CONTRACT = ("make_pool", "deploy_params", "forward", "decision_paths",
+            "cbr_layers", "mapping_flops")
 
 #: Clouds checked against the reference in each run (per tenant).
 CHECK_SAMPLE = 64
@@ -69,11 +81,53 @@ def load_json(path: pathlib.Path) -> Dict:
         return json.load(f)
 
 
-def load_config(name: str) -> Dict:
-    c = load_json(HERE / "configs" / f"{name}.json")
-    for tier, sub in c.get("tiers", {}).items():
-        c["tiers"][tier] = load_config(sub)
+def reference_module(name: str, file: str):
+    """The module at ``file`` (relative to this directory) that
+    configuration ``name`` names as its reference, loaded once per file
+    and checked against :data:`CONTRACT`."""
+    path = (HERE / file).resolve()
+    if HERE not in path.parents or path.suffix != ".py":
+        raise ValueError(f"configuration {name!r}: reference {file!r} is "
+                         f"not a Python file under {HERE}")
+    key = f"reference_module:{path.relative_to(HERE)}"
+    mod = sys.modules.get(key)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    missing = [f for f in CONTRACT if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"configuration {name!r}: its reference "
+                             f"{file!r} lacks {', '.join(missing)}")
+    return mod
+
+
+def load_config(name: str, directory: pathlib.Path = HERE / "configs"
+                ) -> Dict:
+    """The configuration ``<directory>/<name>.json``, its reference
+    module kept under ``work.MODULE``; a fleet's tiers each keep their
+    own."""
+    c = load_json(directory / f"{name}.json")
+    if "tiers" in c:
+        for tier, sub in c["tiers"].items():
+            c["tiers"][tier] = load_config(sub, directory)
+        return c
+    if "reference" not in c:
+        raise KeyError(f"configuration {name!r} names no reference module")
+    c[work.MODULE] = reference_module(name, c["reference"])
     return c
+
+
+def pool_shapes(c: Dict) -> List[Tuple[int, ...]]:
+    """The shapes of a one-request pool of ``c``, traced, not computed."""
+    key = jax.ShapeDtypeStruct((2,), np.uint32)
+    out = jax.eval_shape(lambda k: c[work.MODULE].make_pool(k, c, 1), key)
+    return [tuple(a.shape) for a in out]
 
 
 def load_cell(workload: str, bench: Optional[Dict] = None) -> Cell:
@@ -100,9 +154,16 @@ def make_cell(name: str, chips: int, config: str, traffic: str,
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if applies(m) and m["moves"] in names]
-    return Cell(name, chips, load_config(config),
-                load_json(HERE / "traffic" / f"{traffic}.json"), e2e,
-                per_layer)
+    c = load_config(config)
+    t = load_json(HERE / "traffic" / f"{traffic}.json")
+    if t["kind"] == "stream":
+        shapes = pool_shapes(c)
+        if len(shapes) != 1 or len(shapes[0]) != 3 or shapes[0][2] != 3:
+            raise ValueError(
+                f"configuration {config!r} cannot serve stream cell "
+                f"{name!r}: a stream sends xyz frames [P, N, 3], but its "
+                f"reference's make_pool gives arrays of shapes {shapes}")
+    return Cell(name, chips, c, t, e2e, per_layer)
 
 
 def require_chips(chips: int):
@@ -168,11 +229,22 @@ class Served:
     target: object                  # AsyncPointCloudEngine | PipelineFleet
     engines: Dict[str, object]      # tenant ("" single) -> engine
     configs: Dict[str, Dict]        # tenant -> model config
-    pools: Dict[str, np.ndarray]    # tenant -> [P, N, 3] clouds
+    pools: Dict[str, Tuple[np.ndarray, ...]]  # tenant -> payloads [P, ...]
     streams: Optional[np.ndarray]   # [sessions, frames, N, 3]
     lfsr_seed: int
     max_batch: int
     threshold: Optional[float] = None
+
+
+def make_pool(c: Dict, key, size: int) -> Tuple[np.ndarray, ...]:
+    """``size`` requests' payloads, made by the configuration's module."""
+    return tuple(np.asarray(a) for a in c[work.MODULE].make_pool(key, c, size))
+
+
+def payload(pool: Tuple[np.ndarray, ...], which) -> Tuple[np.ndarray, ...]:
+    """Request ``which`` of ``pool`` (what ``submit(*payload)`` sends),
+    or with an index array those requests stacked."""
+    return tuple(a[which] for a in pool)
 
 
 def build_served(cell: Cell, seed: int, seconds: float) -> Served:
@@ -195,8 +267,8 @@ def build_served(cell: Cell, seed: int, seconds: float) -> Served:
             specs.append(spec)
             params[spec.name] = init_weights(spec, seed + i)
             configs[tenant] = tc
-            pools[tenant] = np.asarray(clouds.make_batch(
-                jax.random.fold_in(ckey, i), tc["n_points"], t["pool"]))
+            pools[tenant] = make_pool(tc, jax.random.fold_in(ckey, i),
+                                      t["pool"])
         fspec = FleetSpec(
             pipelines=tuple(specs),
             tenants=tuple(TenantSpec(tn, tiers[tn]["name"], slo_ms=0.0,
@@ -225,8 +297,7 @@ def build_served(cell: Cell, seed: int, seconds: float) -> Served:
         streams = np.asarray(clouds.make_streams(
             ckey, c["n_points"], frames, t["sessions"], t["drift"]))
     else:
-        pools[""] = np.asarray(clouds.make_batch(ckey, c["n_points"],
-                                                 t["pool"]))
+        pools[""] = make_pool(c, ckey, t["pool"])
     return Served("engine", eng, {"": eng}, {"": c}, pools, streams,
                   lfsr_seed, c["max_batch"],
                   t["drift_threshold"] if stream else None)
@@ -247,7 +318,8 @@ def warm_up(served: Served, cell: Cell) -> None:
         if pool is None:
             continue
         for k in sizes:
-            futs = [eng.submit(pool[i % len(pool)]) for i in range(k)]
+            futs = [eng.submit(*payload(pool, i % len(pool[0])))
+                    for i in range(k)]
             eng.flush()
             for f in futs:
                 np.asarray(f.result())
@@ -407,9 +479,10 @@ def run_backlog(served: Served, cell: Cell, seconds: float,
 
     def top_up():
         while eng.depth < depth:
-            r = Rec(clock(), cloud=len(recs) % len(pool))
+            r = Rec(clock(), cloud=len(recs) % len(pool[0]))
             r.t_submit = r.due
-            eng.submit(pool[r.cloud]).add_done_callback(_on_done(r, clock))
+            eng.submit(*payload(pool, r.cloud)).add_done_callback(
+                _on_done(r, clock))
             recs.append(r)
 
     top_up()
@@ -468,14 +541,14 @@ def run_open(served: Served, cell: Cell, seconds: float, seed: int,
             depths = {tn: e.depth for tn, e in engines.items()}
             from repro.serve.admission import Overloaded
             try:
-                fut = target.submit(rec.tenant,
-                                    served.pools[rec.tenant][rec.cloud])
+                fut = target.submit(rec.tenant, *payload(
+                    served.pools[rec.tenant], rec.cloud))
             except Overloaded:
                 rec.shed = True
                 return
             tn = next(n for n, e in engines.items() if e.depth > depths[n])
         else:
-            fut = target.submit(served.pools[""][rec.cloud])
+            fut = target.submit(*payload(served.pools[""], rec.cloud))
             tn = ""
         fifo[tn].append(rec)
         fut.add_done_callback(_on_done(rec, clock))
@@ -653,72 +726,69 @@ def cross_rounding(mode: str):
 
 
 def _params(c: Dict, weight_seed: int, bits: Optional[int]):
-    return reference.deploy_params(prng_key(weight_seed, SALT_WEIGHTS),
-                                   c, bits=bits)
+    return c[work.MODULE].deploy_params(prng_key(weight_seed, SALT_WEIGHTS),
+                                        c, bits)
 
 
-def reference_logits(c: Dict, weight_seed: int, inputs: np.ndarray,
-                     keys: Optional[np.ndarray],
-                     lfsr_seed: int, mode: Optional[str] = None,
-                     bits: Optional[int] = None) -> np.ndarray:
-    """The plain reference over ``inputs``, with its own float32
-    decisions: hits replay those of their key frames ``keys``; misses
-    and single clouds compute theirs.  ``mode`` and ``bits`` default to
-    what the configuration states."""
+def reference_forward(c: Dict, weight_seed: int,
+                      inputs: Tuple[np.ndarray, ...],
+                      keys: Optional[Tuple[np.ndarray, ...]],
+                      lfsr_seed: int, mode: Optional[str] = None,
+                      bits: Optional[int] = None) -> np.ndarray:
+    """The plain reference's answers to the stacked payload ``inputs``,
+    with its own float32 decisions: hits replay those of their key
+    frames ``keys``; misses and single requests compute theirs.
+    ``mode`` and ``bits`` default to what the configuration states."""
     mode = mode or reference_mode(c)
     if bits is None and c["precision"] == "int8":
         bits = c["a_bits"]
+    forward = c[work.MODULE].forward
     params = _params(c, weight_seed, bits)
     cache = None
     if keys is not None:
-        _, cache = reference.forward(params, c, keys, lfsr_seed=lfsr_seed,
-                                     mode=mode, bits=bits)
-    logits, _ = reference.forward(params, c, inputs, lfsr_seed=lfsr_seed,
-                                  mode=mode, bits=bits, cache=cache)
-    return logits
+        _, cache = forward(params, c, keys, lfsr_seed=lfsr_seed, mode=mode,
+                           bits=bits, cache=None)
+    answers, _ = forward(params, c, inputs, lfsr_seed=lfsr_seed, mode=mode,
+                         bits=bits, cache=cache)
+    return answers
 
 
-def open_decision_logits(c: Dict, weight_seed: int, inputs: np.ndarray,
-                         deciders: np.ndarray, lfsr_seed: int
-                         ) -> List[np.ndarray]:
-    """Per input, the reference's logits on every decision path that
-    :func:`decisions.paths` lists for its decider (the input itself, or
-    for a stream hit its key frame): the exact path and those that flip
-    its open decisions."""
+def open_decision_answers(c: Dict, weight_seed: int,
+                          inputs: Tuple[np.ndarray, ...],
+                          deciders: Tuple[np.ndarray, ...], lfsr_seed: int
+                          ) -> List[np.ndarray]:
+    """Per input, the reference's answers on every decision path that
+    the configuration's ``decision_paths`` lists for its decider (the
+    input itself, or for a stream hit its key frame): the exact path
+    and those that flip its open decisions."""
+    mod = c[work.MODULE]
     mode = reference_mode(c)
     bits = c["a_bits"] if c["precision"] == "int8" else None
-    urs = None
-    if c["sampler"] == "urs":
-        sizes, n = [], c["n_points"]
-        for m in decisions.stage_samples(c):
-            sizes.append((n, m))
-            n = m
-        urs = reference.lfsr_indices(lfsr_seed, sizes)
-    rnd = cross_rounding(mode)
     owner, found = [], []
-    for r, cloud in enumerate(deciders):
-        for p in decisions.paths(c, cloud, urs, rnd):
-            owner.append(r)
-            found.append(p)
-    cache = jax.tree_util.tree_map(lambda *a: np.stack(a), *found)
-    logits, _ = reference.forward(_params(c, weight_seed, bits), c,
-                                  inputs[np.asarray(owner)],
-                                  lfsr_seed=lfsr_seed, mode=mode,
-                                  bits=bits, cache=cache)
+    for r, paths in enumerate(mod.decision_paths(c, deciders, lfsr_seed,
+                                                 cross_rounding(mode))):
+        owner += [r] * len(paths)
+        found += paths
     owner = np.asarray(owner)
-    return [logits[owner == r] for r in range(len(inputs))]
+    cache = jax.tree_util.tree_map(lambda *a: np.stack(a), *found)
+    answers, _ = mod.forward(_params(c, weight_seed, bits), c,
+                             payload(inputs, owner), lfsr_seed=lfsr_seed,
+                             mode=mode, bits=bits, cache=cache)
+    return [answers[owner == r] for r in range(len(inputs[0]))]
 
 
 def checked_inputs(served: Served, tenant: str, picked: List[Rec]):
-    """The clouds the picked requests sent, and for stream hits the key
-    frames whose indices they replay (None where nothing replays)."""
+    """The payloads the picked requests sent, stacked, and for stream
+    hits the key frames whose decisions they replay (None where nothing
+    replays)."""
     if served.streams is None:
-        return np.stack([served.pools[tenant][r.cloud] for r in picked]), None
+        rows = np.array([r.cloud for r in picked])
+        return payload(served.pools[tenant], rows), None
     inputs = np.stack([served.streams[r.session, r.frame] for r in picked])
     miss = stream_decisions(served)
     is_hit = np.array([not miss[(r.session, r.frame)] for r in picked])
     keys = key_frames(served, miss, picked)
-    return inputs, (keys, is_hit)
+    return (inputs,), ((keys,), is_hit)
 
 
 def reference_answers(served: Served, picked: Dict[str, List[Rec]],
@@ -727,9 +797,9 @@ def reference_answers(served: Served, picked: Dict[str, List[Rec]],
                       open_decisions: bool = True
                       ) -> Dict[str, List[np.ndarray]]:
     """Per tenant and picked request, the answers the reference accepts
-    [candidates, n_classes]: first its own float32 forward, then (with
-    ``open_decisions``) the forwards on each decision path that float32
-    rounding leaves open."""
+    [candidates, ...answer's shape]: first its own float32 forward, then
+    (with ``open_decisions``) the forwards on each decision path that
+    float32 rounding leaves open."""
     out = {}
     for tn, recs in sorted(picked.items()):
         c = served.configs[tn]
@@ -739,34 +809,36 @@ def reference_answers(served: Served, picked: Dict[str, List[Rec]],
             continue
         inputs, stream = checked_inputs(served, tn, recs)
         if stream is None:
-            own = reference_logits(c, wseed, inputs, None,
-                                   served.lfsr_seed, mode, bits)
+            own = reference_forward(c, wseed, inputs, None,
+                                    served.lfsr_seed, mode, bits)
             deciders = inputs
         else:
             keys, is_hit = stream
-            own = np.empty((len(recs), c["n_classes"]), np.float32)
-            if (~is_hit).any():
-                own[~is_hit] = reference_logits(
-                    c, wseed, inputs[~is_hit], None, served.lfsr_seed,
-                    mode, bits)
-            if is_hit.any():
-                own[is_hit] = reference_logits(
-                    c, wseed, inputs[is_hit], keys[is_hit],
+            own = None
+            for rows, replayed in ((~is_hit, None), (is_hit, keys)):
+                if not rows.any():
+                    continue
+                part = reference_forward(
+                    c, wseed, payload(inputs, rows),
+                    None if replayed is None else payload(replayed, rows),
                     served.lfsr_seed, mode, bits)
-            deciders = np.where(is_hit[:, None, None], keys, inputs)
+                if own is None:
+                    own = np.empty((len(recs),) + part.shape[1:], part.dtype)
+                own[rows] = part
+            deciders = (np.where(is_hit[:, None, None], keys[0], inputs[0]),)
         cands = [o[None] for o in own]
         if open_decisions:
-            more = open_decision_logits(c, wseed, inputs, deciders,
-                                        served.lfsr_seed)
+            more = open_decision_answers(c, wseed, inputs, deciders,
+                                         served.lfsr_seed)
             cands = [np.concatenate([a, b]) for a, b in zip(cands, more)]
         out[tn] = cands
     return out
 
 
 def request_gaps(got: np.ndarray, accepted: List[np.ndarray]) -> np.ndarray:
-    """Per checked request, the widest |served - reference| over its
-    logits, against the nearest answer the reference accepts (inf for
-    an answer of the wrong shape)."""
+    """Per checked request, the widest |served - accepted| over every
+    element of its answer, against the nearest answer the reference
+    accepts (inf for an answer of the wrong shape)."""
     if len(got) != len(accepted):
         return np.full(len(accepted), np.inf)
     out = np.empty(len(accepted))
@@ -774,7 +846,8 @@ def request_gaps(got: np.ndarray, accepted: List[np.ndarray]) -> np.ndarray:
         if np.shape(g) != cands.shape[1:]:
             out[i] = np.inf
             continue
-        out[i] = float(np.min(np.max(np.abs(cands - g), axis=-1)))
+        widest = np.abs(cands - g).reshape(len(cands), -1).max(axis=1)
+        out[i] = float(widest.min())
     return out
 
 

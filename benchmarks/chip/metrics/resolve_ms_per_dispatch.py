@@ -1,7 +1,8 @@
-"""Host time the engines spend resolving a retired dispatch: splitting
-it into rows, resolving the futures and running their done-callbacks
-(their own ``stats.resolve_s / stats.retired``, the ``serve.resolve``
-span), over the window.  None where the engine keeps no such counter."""
+"""Host time the engines spend resolving a retired dispatch, whose
+answers are already one host copy: resolving its futures, running their
+done-callbacks and refreshing stream sessions (their own
+``stats.resolve_s / stats.retired``, the ``serve.resolve`` span), over
+the window.  None where the engine keeps no such counter."""
 
 
 def read(run):

@@ -12,6 +12,23 @@ deployment's: one LFSR sequence shared by every cloud, normalization
 statistics per cloud, and, for a stream frame that replays its key
 frame, the key frame's sample and neighbour indices.
 
+It is the ``reference`` module of both PointMLP classification
+configurations, and so provides the contract through which the harness
+reaches everything model-specific (``harness.CONTRACT``):
+
+- ``make_pool(key, c, size)``: the traffic's inputs, a tuple of arrays
+  with a leading request axis; here ``(xyz [size, N, 3],)``.  A request
+  is sent as ``submit(*payload)``, one row of each array.
+- ``deploy_params(key, c, bits)``: the reference's weights from a key.
+- ``forward(params, c, inputs, *, lfsr_seed, mode, bits, cache)``: the
+  answers ``[B, ...]`` of the stacked payload ``inputs`` and the cache
+  of decisions they used (replayed where ``cache`` is given).
+- ``decision_paths(c, deciders, lfsr_seed, rnd)``: per input of the
+  stacked payload ``deciders``, the caches of every decision path that
+  float32 rounding leaves open (``decisions.py``).
+- ``cbr_layers(c)``, ``mapping_flops(c)``: the work of one input, from
+  the configuration's shapes (``work.py`` sums and prices them).
+
 ``mode`` names the arithmetic of the fp32 matmuls: ``"highest"`` is
 full float32; ``"high"`` is the three-pass bfloat16 product
 (hi*hi + hi*lo + lo*hi), written out so that it means the same on
@@ -29,6 +46,10 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import decisions
+from traffic import clouds as cloud_traffic
+from work import Layer
 
 HIGHEST = jax.lax.Precision.HIGHEST
 BN_EPS = 1e-5
@@ -291,18 +312,27 @@ def _freeze(c: Dict) -> tuple:
                         for k, v in c.items()))
 
 
-def forward(params: Dict, c: Dict, clouds: np.ndarray, *, lfsr_seed: int,
-            mode: str = "highest", bits: Optional[int] = None,
-            cache=None, block: int = 16):
-    """Logits of ``clouds`` [R, N, 3] (and the indices they used), in
-    blocks of ``block`` clouds so that any R fits."""
-    urs_idx = None
-    if c["sampler"] == "urs":
-        sizes, n = [], c["n_points"]
-        for m in stage_samples(c):
-            sizes.append((n, m))
-            n = m
-        urs_idx = tuple(jnp.asarray(a) for a in lfsr_indices(lfsr_seed, sizes))
+def urs_indices(c: Dict, lfsr_seed: int) -> Optional[List[np.ndarray]]:
+    """The shared URS indices of every stage (None for FPS)."""
+    if c["sampler"] != "urs":
+        return None
+    sizes, n = [], c["n_points"]
+    for m in stage_samples(c):
+        sizes.append((n, m))
+        n = m
+    return lfsr_indices(lfsr_seed, sizes)
+
+
+def forward(params: Dict, c: Dict, inputs: Tuple[np.ndarray], *,
+            lfsr_seed: int, mode: str = "highest",
+            bits: Optional[int] = None, cache=None, block: int = 16):
+    """Logits of the clouds ``inputs = (xyz [R, N, 3],)`` (and the
+    indices they used), in blocks of ``block`` clouds so that any R
+    fits."""
+    clouds, = inputs
+    urs_idx = urs_indices(c, lfsr_seed)
+    if urs_idx is not None:
+        urs_idx = tuple(jnp.asarray(a) for a in urs_idx)
     shape_keys = ("n_points", "n_classes", "embed_dim", "k_neighbors",
                   "stage_expansion", "pre_blocks", "pos_blocks",
                   "res_expansion", "affine_mode", "sampler")
@@ -324,3 +354,48 @@ def forward(params: Dict, c: Dict, clouds: np.ndarray, *, lfsr_seed: int,
         caches.append(jax.tree_util.tree_map(lambda a: np.asarray(a)[:n], ch))
     cat = jax.tree_util.tree_map(lambda *a: np.concatenate(a), *caches)
     return np.concatenate(logits), cat
+
+
+# ----------------------------------------------- rest of the contract ----
+
+def make_pool(key, c: Dict, size: int) -> Tuple[np.ndarray]:
+    """``size`` synthetic clouds of the configuration's point count."""
+    return (cloud_traffic.make_batch(key, c["n_points"], size),)
+
+
+def decision_paths(c: Dict, deciders: Tuple[np.ndarray], lfsr_seed: int,
+                   rnd) -> List[List[decisions.Path]]:
+    """Per cloud of ``deciders``, the (sample, neighbour) indices of
+    every decision path :func:`decisions.paths` lists for it."""
+    urs = urs_indices(c, lfsr_seed)
+    return [decisions.paths(c, cloud, urs, rnd) for cloud in deciders[0]]
+
+
+def cbr_layers(c: Dict) -> List[Layer]:
+    """Every CBR layer of one cloud's forward, in order."""
+    k = c["k_neighbors"]
+    out = [Layer("embed", c["n_points"], 3, c["embed_dim"])]
+    c_prev = c["embed_dim"]
+    for s, (smp, ch) in enumerate(zip(stage_samples(c), stage_dims(c))):
+        mid = res_mid(c, ch)
+        out.append(Layer(f"stage{s + 1}.transfer", smp * k, 2 * c_prev, ch))
+        for branch, rows in (("pre", smp * k), ("pos", smp)):
+            for i in range(c[f"{branch}_blocks"][s]):
+                out.append(Layer(f"stage{s + 1}.{branch}{i}.net1", rows, ch,
+                                 mid))
+                out.append(Layer(f"stage{s + 1}.{branch}{i}.net2", rows, mid,
+                                 ch))
+        c_prev = ch
+    out += [Layer("head.fc1", 1, c_prev, 512), Layer("head.fc2", 1, 512, 256),
+            Layer("head.fc3", 1, 256, c["n_classes"])]
+    return out
+
+
+def mapping_flops(c: Dict) -> int:
+    """The kNN distance matmuls of one cloud (2*S*N*3 per stage); a
+    stream frame that replays its key frame's neighbours skips them."""
+    total, n = 0, c["n_points"]
+    for smp in stage_samples(c):
+        total += 2 * smp * n * 3
+        n = smp
+    return total

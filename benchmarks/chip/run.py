@@ -9,7 +9,8 @@ weights and the traffic from ``--seed``, builds the served path and
 warms every shape the window uses, reading compiled programs from the
 compile cache inside the checkout.  Then the window drives the served
 path for ``--seconds``; afterwards a sample of its answers is compared
-with the plain reference (``reference.py``).
+with the plain reference, the module the configuration's ``reference``
+key names.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 traces part of the window with the JAX profiler and reports its

@@ -11,20 +11,19 @@ import numpy as np
 import pytest
 
 import harness
-from traffic import clouds
 
 
 @pytest.mark.parametrize("config", ["pointmlp-elite", "pointmlp-lite"])
 @pytest.mark.parametrize("seed", [7, 2 ** 31 + 5])
 def test_control_fails_the_limit(config, seed):
     c = harness.load_config(config)
-    pts = np.asarray(clouds.make_batch(
-        harness.prng_key(seed, harness.SALT_CLOUDS), c["n_points"], 16))
-    own = harness.reference_logits(c, seed, pts, None, seed)
-    more = harness.open_decision_logits(c, seed, pts, pts, seed)
+    pts = harness.make_pool(c, harness.prng_key(seed, harness.SALT_CLOUDS),
+                            16)
+    own = harness.reference_forward(c, seed, pts, None, seed)
+    more = harness.open_decision_answers(c, seed, pts, pts, seed)
     accepted = [np.concatenate([a[None], b]) for a, b in zip(own, more)]
     low = {"bits": 4} if c["precision"] == "int8" else {"mode": "high"}
-    got = harness.reference_logits(c, seed, pts, None, seed, **low)
+    got = harness.reference_forward(c, seed, pts, None, seed, **low)
     gaps = harness.request_gaps(got, accepted)
     lim = c["limits"]
     assert harness.off_share(gaps, lim["request_gap"]) > lim["off_share"]

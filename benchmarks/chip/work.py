@@ -3,14 +3,21 @@ peaks of the chips the benchmark knows.
 
 Counted from the configuration's shapes alone, never from the kernels
 that happen to run a layer, so the yardstick stays put when a kernel is
-fused or replaced.  One conv (CBR) layer of one cloud is a matmul
+fused or replaced.  One conv (CBR) layer of one input is a matmul
 [M, K] @ [K, N]: 2*M*K*N operations, and it reads its input, weight and
-bias and writes its output once.
+bias and writes its output once.  Which layers an input runs, and what
+mapping work besides, the configuration's reference module says
+(``cbr_layers``, ``mapping_flops``); the sums and prices here are the
+same for every configuration.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict
+
+#: Key under which ``harness.load_config`` keeps a configuration's
+#: reference module, the one its ``reference`` file names.
+MODULE = "_module"
 
 #: Published peaks per chip, keyed by ``jax.Device.device_kind``.
 #: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
@@ -59,60 +66,19 @@ class Layer:
             + 4 * self.n + 4 * self.m * self.n
 
 
-def _samples(c: Dict) -> List[int]:
-    return [c["n_points"] // 2 ** (i + 1) for i in range(4)]
-
-
-def _dims(c: Dict) -> List[int]:
-    dims, d = [], c["embed_dim"]
-    for e in c["stage_expansion"]:
-        d *= e
-        dims.append(d)
-    return dims
-
-
-def cbr_layers(c: Dict) -> List[Layer]:
-    """Every CBR layer of one cloud's forward, in order."""
-    k = c["k_neighbors"]
-    out = [Layer("embed", c["n_points"], 3, c["embed_dim"])]
-    c_prev = c["embed_dim"]
-    for s, (smp, ch) in enumerate(zip(_samples(c), _dims(c))):
-        mid = max(1, int(ch * c["res_expansion"]))
-        out.append(Layer(f"stage{s + 1}.transfer", smp * k, 2 * c_prev, ch))
-        for branch, rows in (("pre", smp * k), ("pos", smp)):
-            for i in range(c[f"{branch}_blocks"][s]):
-                out.append(Layer(f"stage{s + 1}.{branch}{i}.net1", rows, ch,
-                                 mid))
-                out.append(Layer(f"stage{s + 1}.{branch}{i}.net2", rows, mid,
-                                 ch))
-        c_prev = ch
-    out += [Layer("head.fc1", 1, c_prev, 512), Layer("head.fc2", 1, 512, 256),
-            Layer("head.fc3", 1, 256, c["n_classes"])]
-    return out
-
-
-def mapping_flops(c: Dict) -> int:
-    """The kNN distance matmuls of one cloud (2*S*N*3 per stage); a
-    stream frame that replays its key frame's neighbours skips them."""
-    total, n = 0, c["n_points"]
-    for smp in _samples(c):
-        total += 2 * smp * n * 3
-        n = smp
-    return total
-
-
 def cloud_flops(c: Dict, replayed: bool = False) -> int:
-    """Operations of one cloud's forward: every CBR layer plus, unless
-    the neighbours are replayed, the kNN distances."""
-    cbr = sum(layer.flops for layer in cbr_layers(c))
-    return cbr + (0 if replayed else mapping_flops(c))
+    """Operations of one input's forward: every CBR layer plus, unless
+    its decisions are replayed, the mapping work (for PointMLP the kNN
+    distances)."""
+    cbr = sum(layer.flops for layer in c[MODULE].cbr_layers(c))
+    return cbr + (0 if replayed else c[MODULE].mapping_flops(c))
 
 
 def cbr_bound_s(c: Dict, device_kind: str) -> float:
-    """Least time the chip could spend on one cloud's CBR layers: per
+    """Least time the chip could spend on one input's CBR layers: per
     layer the larger of operations over peak and bytes over HBM
     bandwidth, summed."""
     peak = compute_peak(device_kind, c["precision"])
     bw = peaks(device_kind)["hbm_bytes_per_s"]
     return sum(max(layer.flops / peak, layer.bytes(c["precision"]) / bw)
-               for layer in cbr_layers(c))
+               for layer in c[MODULE].cbr_layers(c))
