@@ -45,6 +45,9 @@ CODES = {
     "RPA015": (ERROR, "stream sampler does not declare advances_state"),
     "RPA016": (ERROR, "fused op does not compile on a TPU"),
     "RPA017": (ERROR, "interpret-mode Pallas kernel on a TPU"),
+    "RPA018": (ERROR, "stream=True cannot serve a request of more than "
+                      "xyz"),
+    "RPA019": (ERROR, "fused_group cannot group with use_xyz"),
     "RPA020": (ERROR, "data_shards > 1 requires per_sample_norm"),
     "RPA030": (ERROR, "stream session over a non-streaming pipeline"),
     # --- soft misconfigurations (escalated in-tree via the code

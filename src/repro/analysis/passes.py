@@ -138,6 +138,12 @@ def fused_preconditions(spec) -> List[Finding]:
             f"fused_group={fused!r} requires fp32 transfer layers; "
             f"stages {bad} resolve to int8 (stage_precision / "
             f"precision)"))
+    if spec.use_xyz:
+        out.append(finding(
+            "RPA019", "spec.use_xyz",
+            f"fused_group={fused!r} normalizes features alone; it cannot "
+            f"carry the neighbours' xyz (use_xyz=True): use "
+            f"fused_group='none'"))
     if not spec.fuse:
         out.append(finding(
             "RPA012", "spec.fuse",
@@ -148,10 +154,18 @@ def fused_preconditions(spec) -> List[Finding]:
 
 @register_pass("stream-contract", scope="lowering")
 def stream_contract(spec) -> List[Finding]:
-    """RPA013-015: the stream-cache lowering contract."""
+    """RPA013-015, RPA018: the stream-cache lowering contract."""
     if not getattr(spec, "stream", False):
         return []
     out: List[Finding] = []
+    if spec.head == "partseg" or spec.in_channels != 3:
+        out.append(finding(
+            "RPA018", "spec.head",
+            f"stream=True cannot serve head={spec.head!r} with "
+            f"in_channels={spec.in_channels}: a stream session sends one "
+            f"xyz frame [N, 3] per request, and this model needs more "
+            f"per point (normals) or per request (a category); serve it "
+            f"with submit(points, category) on a stream=False spec"))
     if spec.fused_group != "none":
         out.append(finding(
             "RPA013", "spec.fused_group",

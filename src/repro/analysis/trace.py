@@ -225,18 +225,19 @@ def _cbr_shape_walk(plan, cfg) -> List[Tuple[Any, int, int]]:
     topology walk ``cost_breakdown`` uses (one source of truth for
     channel dims)."""
     from repro.api import plan as plan_mod
+    from repro.models.pointmlp import partseg_levels
     out: List[Tuple[Any, int, int]] = []
     c_prev = cfg.embed_dim
     for op in plan.ops:
         if isinstance(op, plan_mod.EmbedOp):
-            out.append((op.cbr, 3, cfg.embed_dim))
+            out.append((op.cbr, cfg.in_channels, cfg.embed_dim))
         elif isinstance(op, plan_mod.FusedGroupTransferOp):
             c = cfg.stage_dims[op.stage]
             out.append((op.cbr, 2 * c_prev, c))
             c_prev = c
         elif isinstance(op, plan_mod.CBROp):          # stage transfer
             c = cfg.stage_dims[op.stage]
-            out.append((op, 2 * c_prev, c))
+            out.append((op, cfg.transfer_in(c_prev), c))
             c_prev = c
         elif isinstance(op, plan_mod.ResBlockOp):
             c = cfg.stage_dims[op.stage]
@@ -248,6 +249,22 @@ def _cbr_shape_walk(plan, cfg) -> List[Tuple[Any, int, int]]:
                       if isinstance(op, plan_mod.SegHeadOp) else c_prev)
             out.append((op.fc1, c_head, 512))
             out.append((op.fc2, 512, 256))
+        elif isinstance(op, plan_mod.PropagateOp):
+            _, _, skip, c_in, w, _ = partseg_levels(cfg)[op.level - 1]
+            mid = max(1, int(w * cfg.res_expansion))
+            out.append((op.fuse, c_in + skip, w))
+            for net1, net2 in op.blocks:
+                out += [(net1, w, mid), (net2, mid, w)]
+            c_prev = w
+        elif isinstance(op, plan_mod.PartSegHeadOp):
+            ctx, cat = cfg.context_dims
+            enc = (cfg.embed_dim,) + cfg.stage_dims
+            out += [(o, c, ctx) for o, c in zip(op.context, reversed(enc))]
+            out.append((op.context_out, len(enc) * ctx, ctx))
+            out += [(op.category[0], cfg.n_categories, cat),
+                    (op.category[1], cat, cat),
+                    (op.classifier, c_prev + ctx + cat, 128),
+                    (op.out, 128, cfg.n_classes)]
     return out
 
 

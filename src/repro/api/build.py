@@ -269,18 +269,22 @@ class FrozenPipeline:
         """Run the frozen pipeline.
 
         Args:
-          pts: [B, N, 3] point clouds (N == spec.n_points).
+          pts: [B, N, 3] point clouds (N == spec.n_points); for
+            ``head="partseg"`` the tuple ``(points [B, N, in_channels],
+            category [B] int32)``.
           lfsr_state: uint32 [>=B] LFSR streams (URS specs only) —
             shorter states used to silently alias streams inside the
             sampler's index math; now rejected here.
 
-        Returns: (logits [B, n_classes], advanced LFSR state).
+        Returns: (logits [B, n_classes] — [B, N, n_classes] for the
+        per-point heads —, advanced LFSR state).
         """
-        if (lfsr_state is not None and pts.ndim >= 1
-                and lfsr_state.shape[0] < pts.shape[0]):
+        points = pts[0] if isinstance(pts, tuple) else pts
+        if (lfsr_state is not None and points.ndim >= 1
+                and lfsr_state.shape[0] < points.shape[0]):
             raise ValueError(
                 f"LFSR state has {lfsr_state.shape[0]} streams for a "
-                f"batch of {pts.shape[0]}; per-lane URS needs one "
+                f"batch of {points.shape[0]}; per-lane URS needs one "
                 f"stream per lane — size the state from the dispatch "
                 f"batch, e.g. pipeline.seed_state(seed, max_batch)")
         return self._fn(self.params, pts, lfsr_state)

@@ -180,6 +180,38 @@ class SegHeadOp:
     cached: bool = False
 
 
+@dataclasses.dataclass(frozen=True)
+class PropagateOp:
+    """One feature-propagation level of the part-segmentation decoder
+    (``spec.head="partseg"`` lowering rule), coarse to fine.
+
+    Each point of the finer encoder level takes its 3 nearest points
+    of the current (coarser) level, fewer where that level has fewer,
+    picked by the kNN selection the groupers use; weights them by
+    inverse squared distance; concatenates the finer level's skip
+    features with the interpolation; then runs ``fuse`` and the
+    residual ``blocks`` (``(net1, net2)`` pairs, ReLU after the add).
+    """
+    level: int                      # 1..N_STAGES, coarse to fine
+    fuse: CBROp
+    blocks: Tuple[Tuple[CBROp, CBROp], ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class PartSegHeadOp:
+    """The published PointMLP part-segmentation head: per encoder level
+    (coarse first) a ``context`` CBR max-pooled over its points, their
+    concat through ``context_out``; the request's one-hot category
+    through the ``category`` CBRs; per point, the decoder's features
+    with both broadcast, through ``classifier`` (BN folded, no ReLU)
+    and ``out``, then a log-softmax over the parts."""
+    context: Tuple[CBROp, ...]
+    context_out: CBROp
+    category: Tuple[CBROp, ...]
+    classifier: CBROp
+    out: CBROp
+
+
 StageOp = Any   # union of the op dataclasses above
 
 
@@ -201,7 +233,7 @@ class StagePlan:
     precision: str                  # embed + head precision
     backend: str                    # embed + head backend key
     fused_group: str = "none"
-    head: str = "cls"               # "cls" | "seg" (SegHeadOp lowering)
+    head: str = "cls"               # "cls" | "seg" | "partseg"
     stream: bool = False            # cache-aware mapping-op variants
     #: Resolved per-kernel tile sizes (spec.kernel_tuning or the
     #: defaults) — already bound onto the ops' fn callables; kept here
@@ -224,6 +256,13 @@ class StagePlan:
                 out.extend((op.net1, op.net2))
             elif isinstance(op, (HeadOp, SegHeadOp)):
                 out.extend((op.fc1, op.fc2))
+            elif isinstance(op, PropagateOp):
+                out.append(op.fuse)
+                for pair in op.blocks:
+                    out.extend(pair)
+            elif isinstance(op, PartSegHeadOp):
+                out.extend(op.context + (op.context_out,) + op.category
+                           + (op.classifier, op.out))
         return out
 
     @property
@@ -301,7 +340,7 @@ class StagePlan:
                          "w_bytes": w_bytes, "act_bytes": act_bytes})
 
         n, e = cfg.n_points, cfg.embed_dim
-        row("embed", wbytes(3, e, self.precision), 4 * n * e)
+        row("embed", wbytes(cfg.in_channels, e, self.precision), 4 * n * e)
         c_prev = e
         fused = {op.stage for op in self.ops
                  if isinstance(op, FusedGroupTransferOp)}
@@ -314,21 +353,23 @@ class StagePlan:
             # still reads a [S,k,C] gather (all modes except "center"),
             # so fusion halves — not zeroes — the group op's traffic.
             if s not in fused:
-                group_bytes = 4 * smp * k * 2 * c_prev
+                group_bytes = 4 * smp * k * cfg.transfer_in(c_prev)
             elif cfg.affine_mode == "center":
                 group_bytes = 0
             else:
                 group_bytes = 4 * smp * k * c_prev
             row(f"stage{s + 1}.group", 0, group_bytes)
-            row(f"stage{s + 1}.transfer", wbytes(2 * c_prev, c, prec),
-                4 * smp * k * c)
+            row(f"stage{s + 1}.transfer",
+                wbytes(cfg.transfer_in(c_prev), c, prec), 4 * smp * k * c)
             mid = max(1, int(c * cfg.res_expansion))
             blk = wbytes(c, mid, prec) + wbytes(mid, c, prec)
             row(f"stage{s + 1}.pre", cfg.pre_blocks[s] * blk,
                 4 * smp * k * c)
             row(f"stage{s + 1}.pos", cfg.pos_blocks[s] * blk, 4 * smp * c)
             c_prev = c
-        if self.head == "seg":
+        if self.head == "partseg":
+            self._partseg_cost(cfg, row, wbytes)
+        elif self.head == "seg":
             # Per-point head: fc1 consumes the [N, E + 2*C4] skip concat
             # and every activation is n_points wide.
             c_in = cfg.embed_dim + 2 * c_prev
@@ -342,6 +383,28 @@ class StagePlan:
                 + wbytes(256, cfg.n_classes, self.precision),
                 4 * (512 + 256 + cfg.n_classes))
         return rows
+
+    def _partseg_cost(self, cfg, row, wbytes) -> None:
+        """Decoder and head rows: the 3-NN reads both levels' xyz and
+        the coarse features; every conv writes its activations once."""
+        from repro.models.pointmlp import partseg_levels
+        p = self.precision
+        for j, (nf, nc, skip, c_in, w, blocks) in enumerate(
+                partseg_levels(cfg), start=1):
+            mid = max(1, int(w * cfg.res_expansion))
+            row(f"fp{j}.interp", 0, 4 * (3 * (nf + nc) + nc * c_in))
+            row(f"fp{j}", wbytes(c_in + skip, w, p)
+                + blocks * (wbytes(w, mid, p) + wbytes(mid, w, p)),
+                4 * nf * w * (1 + 2 * blocks))
+        ctx, cat = cfg.context_dims
+        enc = (cfg.embed_dim,) + cfg.stage_dims
+        c_last = cfg.decoder[-1][0]
+        w_bytes = (sum(wbytes(c, ctx, p) for c in enc)
+                   + wbytes(len(enc) * ctx, ctx, p)
+                   + wbytes(cfg.n_categories, cat, p) + wbytes(cat, cat, p)
+                   + wbytes(c_last + ctx + cat, 128, p)
+                   + wbytes(128, cfg.n_classes, p))
+        row("partseg", w_bytes, 4 * cfg.n_points * (128 + cfg.n_classes))
 
 
 def _path_stage(path: tuple) -> Optional[int]:
@@ -440,6 +503,8 @@ def _build_ops(cfg, make_cbr: Callable, head_quant: Optional[QuantConfig],
                     net2=make_cbr(base + ("net2",), s, False)))
             if branch == "pre":
                 ops.append(PoolOp(stage=s, axis=2))
+    if head == "partseg":
+        return tuple(ops) + _partseg_ops(cfg, make_cbr)
     head_cls = HeadOp
     if head == "seg":
         head_cls = functools.partial(SegHeadOp, cached=stream)
@@ -448,6 +513,29 @@ def _build_ops(cfg, make_cbr: Callable, head_quant: Optional[QuantConfig],
     ops.append(head_cls(fc1=make_cbr(("head", "fc1"), None, True),
                         fc2=make_cbr(("head", "fc2"), None, True),
                         fc3_path=("head", "fc3"), fc3_quant=head_quant))
+    return tuple(ops)
+
+
+def _partseg_ops(cfg, make_cbr: Callable) -> Tuple[StageOp, ...]:
+    """The decoder levels and the part-segmentation head; every conv
+    follows the spec-level precision and backend."""
+    ops: List[StageOp] = []
+    for j, (_, blocks) in enumerate(cfg.decoder):
+        base = ("decoder", j)
+        ops.append(PropagateOp(
+            level=j + 1, fuse=make_cbr(base + ("fuse",), None, True),
+            blocks=tuple((make_cbr(base + ("blocks", i, "net1"), None, True),
+                          make_cbr(base + ("blocks", i, "net2"), None, False))
+                         for i in range(blocks))))
+    head = ("head",)
+    ops.append(PartSegHeadOp(
+        context=tuple(make_cbr(head + ("context", i), None, True)
+                      for i in range(_N_STAGES + 1)),
+        context_out=make_cbr(head + ("context_out",), None, True),
+        category=tuple(make_cbr(head + ("category", i), None, True)
+                       for i in range(2)),
+        classifier=make_cbr(head + ("classifier",), None, False),
+        out=make_cbr(head + ("out",), None, False)))
     return tuple(ops)
 
 

@@ -18,8 +18,11 @@ Entry contracts
 ---------------
 sampler(xyz [B,N,3], n_samples, lfsr_state, shared) ->
     (idx [B,S] int32, new_lfsr_state)
-grouper(xyz, feats, idx, k, affine_params, mode, per_sample_norm) ->
+grouper(xyz, feats, idx, k, affine_params, mode, per_sample_norm
+        [, use_xyz=True]) ->
     (new_xyz [B,S,3], center_feats [B,S,C], grouped [B,S,k,2C])
+    — ``use_xyz=True`` is passed only for specs that set it, and then
+    the grouped output is [B,S,k,2C+3].
 backend(p, x, quant, act) -> y
     — one Conv(+folded BN)(+ReLU) inference layer; ``p`` is a layer
     param dict (``w`` may be an int8 export dict), ``quant`` a
@@ -137,11 +140,12 @@ _urs_sampler.advances_state = True
 
 @register_grouper("knn")
 def _knn_grouper(xyz, feats, idx, k: int, affine_params, mode: str,
-                 per_sample_norm: bool):
+                 per_sample_norm: bool, use_xyz: bool = False):
     """KNN group + geometric-affine normalize (HLS4PC §2.1, Fig. 2)."""
     from repro.core import knn as knn_core
     return knn_core.group_points(xyz, feats, idx, k, affine_params, mode,
-                                 per_sample_norm=per_sample_norm)
+                                 per_sample_norm=per_sample_norm,
+                                 use_xyz=use_xyz)
 
 
 def _knn_neighbor_index(new_xyz, xyz, k: int):
@@ -190,11 +194,11 @@ def make_ball_grouper(radius: float):
             f"ball-query radius must be positive, got {radius!r}")
 
     def ball_grouper(xyz, feats, idx, k: int, affine_params, mode: str,
-                     per_sample_norm: bool):
+                     per_sample_norm: bool, use_xyz: bool = False):
         from repro.core import knn as knn_core
         return knn_core.group_points(xyz, feats, idx, k, affine_params,
                                      mode, per_sample_norm=per_sample_norm,
-                                     radius=radius)
+                                     radius=radius, use_xyz=use_xyz)
 
     def ball_neighbor_index(new_xyz, xyz, k: int):
         from repro.core import knn as knn_core

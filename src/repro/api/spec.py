@@ -25,8 +25,11 @@ from repro.kernels.tuning import KernelTuning
 
 PRECISIONS = ("fp32", "int8")
 AFFINE_MODES = ("affine", "norm", "center")
-HEADS = ("cls", "seg")
+HEADS = ("cls", "seg", "partseg")
 N_STAGES = 4
+#: Topology fields mirrored one to one by ``PointMLPConfig``.
+_SHAPE_FIELDS = ("in_channels", "stage_reduction", "use_xyz", "decoder",
+                 "context_dims", "n_categories")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +68,22 @@ class PipelineSpec:
     pos_blocks: Tuple[int, ...] = (1, 1, 2, 1)
     res_expansion: float = 0.25
     affine_mode: str = "affine"
+    # ---- encoder shape: channels per input point (3 = xyz; 6 = xyz
+    # and normals, which the embedding reads and the mapping ops skip),
+    # the sample-count reduction of each stage (N -> N/r -> N/r^2 ...),
+    # and ``use_xyz`` (the grouped features carry their xyz, so the
+    # affine takes C+3 channels and the transfer 2C+3). ----
+    in_channels: int = 3
+    stage_reduction: int = 2
+    use_xyz: bool = False
+    # ---- part-segmentation decoder and head (``head="partseg"``):
+    # one ``(width, residual blocks)`` pair per feature-propagation
+    # level, coarse to fine; ``context_dims`` is (global-context width,
+    # category width); ``n_categories`` the object categories a request
+    # names. ----
+    decoder: Tuple[Tuple[int, int], ...] = ()
+    context_dims: Tuple[int, int] = (64, 64)
+    n_categories: int = 16
     # ---- components (registry keys) ----
     sampler: str = "fps"
     grouper: str = "knn"
@@ -90,7 +109,11 @@ class PipelineSpec:
     fused_group: str = "none"
     # ---- task head: "cls" pools to one label per cloud; "seg" lowers
     # a SegHeadOp (1-NN upsample + skip concat + per-point classifier)
-    # emitting per-point logits ``[B, n_points, n_classes]``. ----
+    # emitting per-point logits ``[B, n_points, n_classes]``;
+    # "partseg" lowers the published PointMLP part-segmentation
+    # decoder (one PropagateOp per ``decoder`` level) and a
+    # PartSegHeadOp over a request of points and a category, emitting
+    # per-point log-probabilities ``[B, n_points, n_classes]``. ----
     head: str = "cls"
     # ---- streaming mode: ``stream=True`` lowers cache-aware
     # SampleOp/GroupOp variants so a ``StreamSession``
@@ -162,6 +185,7 @@ class PipelineSpec:
         if self.head not in HEADS:
             raise ValueError(f"head must be one of {HEADS}, "
                              f"got {self.head!r}")
+        self._check_topology()
         if not isinstance(self.stream, bool):
             raise ValueError(f"stream must be a bool, got {self.stream!r}")
         thr = self.stream_drift_threshold
@@ -177,6 +201,54 @@ class PipelineSpec:
         # them out of __post_init__ lets the autotuner *construct* any
         # well-shaped point of the search space and prune it by
         # analyzing, instead of crashing inside replace().
+
+    def _check_topology(self) -> None:
+        """Shape checks of the encoder/decoder fields (lists normalize to
+        tuples, so a spec read from JSON stays hashable)."""
+        dec = self.decoder
+        if isinstance(dec, (list, tuple)):
+            dec = tuple(tuple(level) if isinstance(level, list) else level
+                        for level in dec)
+            object.__setattr__(self, "decoder", dec)
+        if isinstance(self.context_dims, list):
+            object.__setattr__(self, "context_dims",
+                               tuple(self.context_dims))
+        if (not isinstance(dec, tuple) or not all(
+                isinstance(lv, tuple) and len(lv) == 2
+                and all(isinstance(v, int) and v > 0 for v in lv)
+                for lv in dec)):
+            raise ValueError(f"decoder must be a tuple of (width, blocks) "
+                             f"pairs of positive ints, got {dec!r}")
+        for field in ("in_channels", "stage_reduction", "n_categories"):
+            val = getattr(self, field)
+            if not isinstance(val, int) or isinstance(val, bool) or val < 1:
+                raise ValueError(f"{field} must be a positive int, "
+                                 f"got {val!r}")
+        if self.in_channels < 3:
+            raise ValueError(f"in_channels counts xyz first, so it must be "
+                             f">= 3, got {self.in_channels!r}")
+        if self.stage_reduction < 2:
+            raise ValueError(f"stage_reduction must be >= 2 (each stage "
+                             f"samples fewer points), got "
+                             f"{self.stage_reduction!r}")
+        if self.n_points // self.stage_reduction ** N_STAGES < 1:
+            raise ValueError(
+                f"n_points={self.n_points} leaves no sample at stage "
+                f"{N_STAGES} under stage_reduction={self.stage_reduction}")
+        if self.head == "partseg":
+            if len(dec) != N_STAGES:
+                raise ValueError(
+                    f"head='partseg' needs one decoder level per encoder "
+                    f"stage ({N_STAGES}), got decoder={dec!r}")
+            cd = self.context_dims
+            if (not isinstance(cd, tuple) or len(cd) != 2
+                    or not all(isinstance(v, int) and v > 0 for v in cd)):
+                raise ValueError(f"context_dims must be a (context, "
+                                 f"category) pair of positive ints, "
+                                 f"got {cd!r}")
+        elif dec:
+            raise ValueError(f"decoder levels are lowered only for "
+                             f"head='partseg', got head={self.head!r}")
 
     def replace(self, **kw) -> "PipelineSpec":
         return dataclasses.replace(self, **kw)
@@ -250,7 +322,11 @@ class PipelineSpec:
             stage_expansion=self.stage_expansion, pre_blocks=self.pre_blocks,
             pos_blocks=self.pos_blocks, res_expansion=self.res_expansion,
             sampler=self.sampler, affine_mode=self.affine_mode,
-            head=self.head, quant=quant)
+            head=self.head, quant=quant, **self._shape_fields())
+
+    def _shape_fields(self) -> dict:
+        """The encoder/decoder fields spec and model config share."""
+        return {f: getattr(self, f) for f in _SHAPE_FIELDS}
 
     @classmethod
     def from_model_config(cls, cfg, **overrides) -> "PipelineSpec":
@@ -269,7 +345,8 @@ class PipelineSpec:
             stage_expansion=cfg.stage_expansion, pre_blocks=cfg.pre_blocks,
             pos_blocks=cfg.pos_blocks, res_expansion=cfg.res_expansion,
             sampler=cfg.sampler, affine_mode=cfg.affine_mode,
-            head=cfg.head, precision="fp32")
+            head=cfg.head, precision="fp32",
+            **{f: getattr(cfg, f) for f in _SHAPE_FIELDS})
         if cfg.quant.enabled:
             fields.update(precision="int8",
                           w_bits=cfg.quant.w_bits,

@@ -154,20 +154,29 @@ def neighbor_index(new_xyz: jnp.ndarray, xyz: jnp.ndarray, k: int,
 def group_with_idx(xyz: jnp.ndarray, feats: jnp.ndarray,
                    sample_idx: jnp.ndarray, nbr_idx: jnp.ndarray,
                    affine_params: Optional[dict], mode: str,
-                   per_sample_norm: bool = False
+                   per_sample_norm: bool = False, use_xyz: bool = False
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The arithmetic half: gather -> normalize -> concat, indices given.
 
     Same contract as :func:`group_points` but with the neighbor list
     ``nbr_idx`` [B, S, k] supplied (freshly computed or replayed from a
-    stream cache) instead of derived from coordinates.
+    stream cache) instead of derived from coordinates.  ``use_xyz``
+    normalizes each neighbour's features with its xyz appended (anchor
+    likewise), so the grouped output has ``2C + 3`` channels.
     """
     new_xyz = jnp.take_along_axis(xyz, sample_idx[..., None], axis=1)
     center_f = jnp.take_along_axis(feats, sample_idx[..., None], axis=1)
-    grouped = gather_neighbors(feats, nbr_idx)                # [B, S, k, C]
-    grouped = normalize_group(grouped, center_f, affine_params, mode,
+    if use_xyz:
+        grouped = gather_neighbors(jnp.concatenate([feats, xyz], -1),
+                                   nbr_idx)                   # [B,S,k,C+3]
+        anchor = jnp.concatenate([center_f, new_xyz], -1)
+    else:
+        grouped = gather_neighbors(feats, nbr_idx)            # [B, S, k, C]
+        anchor = center_f
+    grouped = normalize_group(grouped, anchor, affine_params, mode,
                               per_sample=per_sample_norm)
-    center_b = jnp.broadcast_to(center_f[:, :, None, :], grouped.shape)
+    center_b = jnp.broadcast_to(center_f[:, :, None, :],
+                                grouped.shape[:3] + center_f.shape[-1:])
     return new_xyz, center_f, jnp.concatenate([grouped, center_b], axis=-1)
 
 
@@ -175,7 +184,7 @@ def group_points(xyz: jnp.ndarray, feats: jnp.ndarray,
                  sample_idx: jnp.ndarray, k: int,
                  affine_params: Optional[dict], mode: str,
                  per_sample_norm: bool = False,
-                 radius: Optional[float] = None
+                 radius: Optional[float] = None, use_xyz: bool = False
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Full local-grouper: sample -> KNN -> gather -> normalize -> concat.
 
@@ -186,13 +195,42 @@ def group_points(xyz: jnp.ndarray, feats: jnp.ndarray,
       radius: None selects plain KNN; a float switches neighbor
         selection to ball query (radius + k cap; the ``ball`` grouper
         registry entry).
+      use_xyz: neighbours carry their xyz into the normalization (see
+        :func:`group_with_idx`).
 
     Returns:
       new_xyz  [B, S, 3], centers' features [B, S, C],
-      grouped  [B, S, k, 2C] (normalized neighbors ++ broadcast center),
-      matching PointMLP's grouper output layout.
+      grouped  [B, S, k, 2C] (normalized neighbors ++ broadcast center;
+      [B, S, k, 2C+3] under ``use_xyz``), matching PointMLP's grouper
+      output layout.
     """
     new_xyz = jnp.take_along_axis(xyz, sample_idx[..., None], axis=1)
     nbr_idx = neighbor_index(new_xyz, xyz, k, radius)         # [B, S, k]
     return group_with_idx(xyz, feats, sample_idx, nbr_idx, affine_params,
-                          mode, per_sample_norm)
+                          mode, per_sample_norm, use_xyz)
+
+
+#: Added to each squared distance before inverting it, so a fine point
+#: that is its own coarse source (distance 0) gets a finite weight.
+INTERP_EPS = 1e-8
+
+
+def interpolate(fine_xyz: jnp.ndarray, coarse_xyz: jnp.ndarray,
+                coarse_feats: jnp.ndarray, k: int = 3) -> jnp.ndarray:
+    """Inverse-squared-distance interpolation (PointNet++ feature
+    propagation): [B, F, 3], [B, S, 3], [B, S, C] -> [B, F, C].
+
+    Each fine point takes its ``min(k, S)`` nearest coarse points,
+    picked by :func:`neighbor_index` (the groupers' kNN selection);
+    their squared distances are then computed by direct differences,
+    so a point that is its own source reads exactly 0 (the expansion
+    form would read a few float32 ulps either side of it).  Weights are
+    ``1 / (d + INTERP_EPS)``, normalized to sum to 1.
+    """
+    nbr = neighbor_index(fine_xyz, coarse_xyz, min(k, coarse_xyz.shape[1]))
+    near = gather_neighbors(coarse_xyz, nbr)                  # [B, F, k, 3]
+    d = jnp.sum((near - fine_xyz[:, :, None, :]) ** 2, axis=-1)
+    w = 1.0 / (d + INTERP_EPS)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return jnp.sum(gather_neighbors(coarse_feats, nbr) * w[..., None],
+                   axis=2)

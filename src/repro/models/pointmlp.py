@@ -41,16 +41,32 @@ class PointMLPConfig:
     res_expansion: float = 0.25           # Elite's slim residual bottleneck
     sampler: str = "fps"                  # fps | urs
     affine_mode: str = "affine"           # affine | norm (alpha/beta pruned)
-    head: str = "cls"                     # cls | seg (per-point logits)
+    head: str = "cls"                     # cls | seg | partseg (per point)
+    # Encoder shape and the part-segmentation decoder/head: see the
+    # PipelineSpec fields of the same names.
+    in_channels: int = 3                  # xyz (+ normals) per point
+    stage_reduction: int = 2              # samples per stage: N / r^(i+1)
+    use_xyz: bool = False                 # grouped features carry xyz
+    decoder: Tuple[Tuple[int, int], ...] = ()   # (width, blocks) per level
+    context_dims: Tuple[int, int] = (64, 64)    # (context, category)
+    n_categories: int = 16
     use_bn: bool = True                   # False after fuse_tree()
     quant: QuantConfig = QuantConfig(w_bits=32, a_bits=32)
     bn_momentum: float = 0.9
 
     @property
     def stage_samples(self) -> Tuple[int, ...]:
-        # numSamp halves per stage: 1024 -> (512,256,128,64);
-        # 512 -> (256,128,64,32) exactly as §2.1.
-        return tuple(self.n_points // (2 ** (i + 1)) for i in range(4))
+        # numSamp halves per stage by default: 1024 -> (512,256,128,64);
+        # 512 -> (256,128,64,32) exactly as §2.1.  Part segmentation
+        # quarters it: 2048 -> (512,128,32,8).
+        r = self.stage_reduction
+        return tuple(self.n_points // r ** (i + 1) for i in range(4))
+
+    def transfer_in(self, c_prev: int) -> int:
+        """Channels into a stage's transfer layer: the normalized
+        neighbours (with their xyz under ``use_xyz``) and the centre's
+        features."""
+        return 2 * c_prev + (3 if self.use_xyz else 0)
 
     @property
     def stage_dims(self) -> Tuple[int, ...]:
@@ -97,15 +113,18 @@ def _res_block_init(key, c, cfg) -> Dict:
 def pointmlp_init(key, cfg: PointMLPConfig) -> Dict:
     keys = jax.random.split(key, 64)
     ki = iter(range(64))
-    params: Dict = {"embed": _cbr_init(keys[next(ki)], 3, cfg.embed_dim, cfg)}
+    params: Dict = {"embed": _cbr_init(keys[next(ki)], cfg.in_channels,
+                                       cfg.embed_dim, cfg)}
     c_prev = cfg.embed_dim
     stages = []
     for s in range(4):
         c_out = cfg.stage_dims[s]
         st: Dict = {}
         if cfg.affine_mode == "affine":
-            st["affine"] = knn_core.geometric_affine_init(c_prev)
-        st["transfer"] = _cbr_init(keys[next(ki)], 2 * c_prev, c_out, cfg)
+            st["affine"] = knn_core.geometric_affine_init(
+                c_prev + (3 if cfg.use_xyz else 0))
+        st["transfer"] = _cbr_init(keys[next(ki)], cfg.transfer_in(c_prev),
+                                   c_out, cfg)
         st["pre"] = [_res_block_init(keys[next(ki)], c_out, cfg)
                      for _ in range(cfg.pre_blocks[s])]
         st["pos"] = [_res_block_init(keys[next(ki)], c_out, cfg)
@@ -113,6 +132,9 @@ def pointmlp_init(key, cfg: PointMLPConfig) -> Dict:
         stages.append(st)
         c_prev = c_out
     params["stages"] = stages
+    if cfg.head == "partseg":
+        params.update(_partseg_init(keys, ki, cfg))
+        return params
     k1, k2, k3 = (keys[next(ki)] for _ in range(3))
     # Seg head fc1 consumes the per-point skip concat
     # [embed_feats (E), upsampled final feats (C4), global max (C4)].
@@ -124,6 +146,31 @@ def pointmlp_init(key, cfg: PointMLPConfig) -> Dict:
                              bn=False),
     }
     return params
+
+
+def _partseg_init(keys, ki, cfg: PointMLPConfig) -> Dict:
+    """The published part-segmentation decoder and head, drawing the
+    keys after the encoder's: per level a fuse CBR and its residual
+    blocks; a CBR per encoder level into the global context (coarse
+    level first) and one over their pooled concat; two CBRs mapping the
+    one-hot category; the classifier (a conv with BN and no ReLU) and
+    the plain output conv."""
+    decoder = [{"fuse": _cbr_init(keys[next(ki)], c_in + skip, width, cfg),
+                "blocks": [_res_block_init(keys[next(ki)], width, cfg)
+                           for _ in range(blocks)]}
+               for _, _, skip, c_in, width, blocks in partseg_levels(cfg)]
+    enc = (cfg.embed_dim,) + cfg.stage_dims
+    ctx, cat = cfg.context_dims
+    head = {"context": [_cbr_init(keys[next(ki)], c, ctx, cfg)
+                        for c in reversed(enc)]}
+    head["context_out"] = _cbr_init(keys[next(ki)], len(enc) * ctx, ctx, cfg)
+    head["category"] = [_cbr_init(keys[next(ki)], cfg.n_categories, cat, cfg),
+                        _cbr_init(keys[next(ki)], cat, cat, cfg)]
+    head["classifier"] = _cbr_init(keys[next(ki)],
+                                   cfg.decoder[-1][0] + ctx + cat, 128, cfg)
+    head["out"] = L.conv1d_init(keys[next(ki)], 128, cfg.n_classes, ksize=1,
+                                bias=True, bn=False)
+    return {"decoder": decoder, "head": head}
 
 
 def count_conv_layers(cfg: PointMLPConfig) -> int:
@@ -265,19 +312,22 @@ _SCOPE_KIND = {
     stage_plan.GroupOp: "group", stage_plan.FusedGroupTransferOp: "group",
     stage_plan.CBROp: "transfer", stage_plan.ResBlockOp: "res",
     stage_plan.PoolOp: "pool", stage_plan.HeadOp: "head",
-    stage_plan.SegHeadOp: "seghead",
+    stage_plan.SegHeadOp: "seghead", stage_plan.PropagateOp: "fp",
+    stage_plan.PartSegHeadOp: "partseg",
 }
 
 
 def op_scope(op) -> str:
     """The ``jax.named_scope`` a plan op runs under: its kind, then its
-    stage where it has one (``sample1``, ``group1``, ``res1``, ``head``).
-    Scopes are HLO metadata (``op_name``) only, so a device trace can
-    tell the plan ops apart; the group op's neighbour search runs under
-    a nested ``knn`` scope (``core/knn.py``)."""
-    stage = getattr(op, "stage", None)
+    stage or decoder level where it has one (``sample1``, ``group1``,
+    ``res1``, ``fp1``, ``head``, ``partseg``).  Scopes are HLO metadata
+    (``op_name``) only, so a device trace can tell the plan ops apart;
+    the group op's neighbour search and a decoder level's 3-NN run
+    under a nested ``knn`` scope (``core/knn.py``)."""
+    index = getattr(op, "stage", getattr(op, "level", None))
     return (_SCOPE_KIND.get(type(op), "op")
-            + ("" if stage is None else str(stage)))
+            + ("" if index is None else str(index)))
+
 
 
 def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
@@ -302,12 +352,25 @@ def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
     """
     if plan is None:
         plan = stage_plan.lower_config(cfg, backend)
+    # A part-segmentation request is (points [B, N, C], category [B]);
+    # the mapping ops read the points' xyz alone.
+    points, category = xyz if isinstance(xyz, tuple) else (xyz, None)
+    xyz = points[..., :3] if points.shape[-1] != 3 else points
+    if train and plan.head == "partseg":
+        raise ValueError("head='partseg' lowers for inference only")
 
     def run_cbr(op, p, x):
         if train:
             return _cbr_apply(p, x, cfg, True, op.act)
         return op.fn(p, x, op.quant, op.act), p
 
+    def cbr_at(op, x):
+        return op.fn(stage_plan.param_at(params, op.path), x, op.quant,
+                     op.act)
+
+    # (xyz, features) of every encoder level, finest first: the
+    # decoder's skip inputs and the part-segmentation head's context.
+    levels = []
     collected_sample, collected_nbr, collected_up = [], [], None
     new_params = {k: v for k, v in params.items()}
     new_stages = [dict(st) for st in params["stages"]]
@@ -319,9 +382,11 @@ def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
     for op in plan.ops:
         with jax.named_scope(op_scope(op)):
             if isinstance(op, stage_plan.EmbedOp):
-                cur, new_params["embed"] = run_cbr(op.cbr, params["embed"], xyz)
+                cur, new_params["embed"] = run_cbr(op.cbr, params["embed"],
+                                                   points)
                 embed_feats = cur
             elif isinstance(op, stage_plan.SampleOp):
+                levels.append((cur_xyz, cur))
                 replay = (op.cached and mapping_cache is not None
                           and not getattr(sampler, "advances_state", True))
                 if replay:
@@ -349,6 +414,10 @@ def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
                     cur_xyz, _, cur = grouper.group_with_idx(
                         cur_xyz, cur, idx, nbr, affine, cfg.affine_mode,
                         per_sample_norm)
+                elif cfg.use_xyz:
+                    cur_xyz, _, cur = grouper(cur_xyz, cur, idx, op.k, affine,
+                                              cfg.affine_mode, per_sample_norm,
+                                              use_xyz=True)
                 else:
                     cur_xyz, _, cur = grouper(cur_xyz, cur, idx, op.k, affine,
                                               cfg.affine_mode, per_sample_norm)
@@ -409,6 +478,37 @@ def _forward_impl(params: Dict, cfg: PointMLPConfig, xyz: jnp.ndarray,
                              if train else op.fc3_quant)
                 logits = L.conv1d_apply(head["fc3"], h, quant=fc3_quant)
                 new_params["head"] = {"fc1": f1, "fc2": f2, "fc3": head["fc3"]}
+            elif isinstance(op, stage_plan.PropagateOp):
+                # Feature propagation, coarse to fine: 3-NN inverse
+                # squared distance interpolation of the coarse features
+                # onto the fine level, skip concat, fuse, res blocks.
+                if op.level == 1:
+                    levels.append((cur_xyz, cur))
+                fine_xyz, skip = levels[-1 - op.level]
+                interp = knn_core.interpolate(fine_xyz, cur_xyz, cur)
+                cur = cbr_at(op.fuse, jnp.concatenate([skip, interp], -1))
+                for net1, net2 in op.blocks:
+                    cur = jax.nn.relu(cbr_at(net2, cbr_at(net1, cur)) + cur)
+                cur_xyz = fine_xyz
+            elif isinstance(op, stage_plan.PartSegHeadOp):
+                # Global context max-pooled from every encoder level
+                # (coarse first), the category's one-hot map, and the
+                # per-point classifier -> log-probabilities per part.
+                pooled = [jnp.max(cbr_at(c_op, f), axis=1)
+                          for c_op, (_, f) in zip(op.context,
+                                                  reversed(levels))]
+                g = cbr_at(op.context_out, jnp.concatenate(pooled, -1))
+                c = jax.nn.one_hot(category, cfg.n_categories,
+                                   dtype=cur.dtype)
+                for c_op in op.category:
+                    c = cbr_at(c_op, c)
+                n = cur.shape[1]
+                h = jnp.concatenate(
+                    [cur, jnp.broadcast_to(g[:, None], (len(g), n, g.shape[-1])),
+                     jnp.broadcast_to(c[:, None], (len(c), n, c.shape[-1]))],
+                    -1)
+                logits = jax.nn.log_softmax(
+                    cbr_at(op.out, cbr_at(op.classifier, h)), axis=-1)
             else:
                 raise TypeError(f"unknown stage-plan op {type(op).__name__}")
     new_params["stages"] = new_stages
@@ -470,10 +570,11 @@ def pointmlp_infer_with(params: Dict, cfg: PointMLPConfig,
     if shared_urs and per_sample_norm:
         def lane(args):
             cloud, mc = args
+            cloud = jax.tree_util.tree_map(lambda a: a[None], cloud)
             mc = (None if mc is None else
                   jax.tree_util.tree_map(lambda a: a[None], mc))
             logits, _, state, cache = _forward_impl(
-                params, cfg, cloud[None], lfsr_state, train=False,
+                params, cfg, cloud, lfsr_state, train=False,
                 sampler=sampler, grouper=grouper, backend=backend,
                 shared_urs=True, per_sample_norm=True, plan=plan,
                 mapping_cache=mc, collect_cache=collect_cache)
@@ -558,27 +659,33 @@ def pointmlp_flops_breakdown(cfg: PointMLPConfig) -> Dict[str, int]:
     """Analytic MAC*2 count per sample, itemized per stage op.
 
     Keys follow the stage-plan op naming (``embed``,
-    ``stage<i>.{group,transfer,pre,pos}``, ``head``); the values sum to
-    exactly :func:`pointmlp_flops` — same arithmetic, one accumulator
-    per op instead of one total.
+    ``stage<i>.{group,transfer,pre,pos}``, ``head``; for part
+    segmentation ``fp<j>.interp`` and ``fp<j>`` per decoder level and
+    ``partseg``); the values sum to exactly :func:`pointmlp_flops` —
+    same arithmetic, one accumulator per op instead of one total.
+    ``group`` and ``interp`` rows are the kNN and 3-NN distance
+    matmuls; every other row is conv layers.
     """
     fl: Dict[str, int] = {}
     n = cfg.n_points
-    fl["embed"] = 2 * n * 3 * cfg.embed_dim
+    fl["embed"] = 2 * n * cfg.in_channels * cfg.embed_dim
     c_prev = cfg.embed_dim
     for s in range(4):
         smp, c = cfg.stage_samples[s], cfg.stage_dims[s]
         k = cfg.k_neighbors
         # knn distances: S x N x C MACs
         fl[f"stage{s + 1}.group"] = 2 * smp * n * 3
-        fl[f"stage{s + 1}.transfer"] = 2 * smp * k * (2 * c_prev) * c
+        fl[f"stage{s + 1}.transfer"] = (2 * smp * k * cfg.transfer_in(c_prev)
+                                        * c)
         mid = max(1, int(c * cfg.res_expansion))
         fl[f"stage{s + 1}.pre"] = (cfg.pre_blocks[s] * 2 * smp * k
                                    * (c * mid + mid * c))
         fl[f"stage{s + 1}.pos"] = (cfg.pos_blocks[s] * 2 * smp
                                    * (c * mid + mid * c))
         n, c_prev = smp, c
-    if cfg.head == "seg":
+    if cfg.head == "partseg":
+        fl.update(_partseg_flops(cfg))
+    elif cfg.head == "seg":
         # 1-NN upsample distances (n_points x S4 x 3 MACs) + the
         # per-point classifier over the [E + 2*C4] skip concat.
         n0 = cfg.n_points
@@ -588,6 +695,39 @@ def pointmlp_flops_breakdown(cfg: PointMLPConfig) -> Dict[str, int]:
     else:
         fl["head"] = 2 * (c_prev * 512 + 512 * 256 + 256 * cfg.n_classes)
     return {op: int(v) for op, v in fl.items()}
+
+
+def partseg_levels(cfg: PointMLPConfig):
+    """Per decoder level, coarse to fine: (fine points, coarse points,
+    skip channels, input channels, width, blocks)."""
+    pts = (cfg.n_points,) + cfg.stage_samples
+    enc = (cfg.embed_dim,) + cfg.stage_dims
+    out, c_prev = [], enc[-1]
+    for j, (width, blocks) in enumerate(cfg.decoder):
+        out.append((pts[-2 - j], pts[-1 - j], enc[-2 - j], c_prev, width,
+                    blocks))
+        c_prev = width
+    return out
+
+
+def _partseg_flops(cfg: PointMLPConfig) -> Dict[str, int]:
+    fl: Dict[str, int] = {}
+    for j, (nf, nc, skip, c_in, w, blocks) in enumerate(
+            partseg_levels(cfg), start=1):
+        mid = max(1, int(w * cfg.res_expansion))
+        fl[f"fp{j}.interp"] = 2 * nf * nc * 3
+        fl[f"fp{j}"] = (2 * nf * (c_in + skip) * w
+                        + blocks * 2 * nf * (w * mid + mid * w))
+    pts = (cfg.n_points,) + cfg.stage_samples
+    enc = (cfg.embed_dim,) + cfg.stage_dims
+    ctx, cat = cfg.context_dims
+    c_last = cfg.decoder[-1][0]
+    fl["partseg"] = 2 * (
+        sum(p * c * ctx for p, c in zip(pts, enc))
+        + len(enc) * ctx * ctx
+        + cfg.n_categories * cat + cat * cat
+        + cfg.n_points * ((c_last + ctx + cat) * 128 + 128 * cfg.n_classes))
+    return fl
 
 
 def pointmlp_flops(cfg: PointMLPConfig) -> int:

@@ -286,7 +286,8 @@ def _tile_waste(plan, cfg, op: str) -> float:
         if kind == "group":
             return 1.0               # gather/normalize, not a matmul
         if kind == "transfer":
-            return (_ceil_waste(smp * k, tm) * _ceil_waste(2 * c_prev, tk)
+            return (_ceil_waste(smp * k, tm)
+                    * _ceil_waste(cfg.transfer_in(c_prev), tk)
                     * _ceil_waste(c, tn))
         # pre/pos residual blocks: two matmuls (c->mid, mid->c); mean
         # of the two directions' waste.

@@ -9,9 +9,10 @@ while the host pads and converts the next batch.
 ``ref`` | ``pallas_interpret`` | ``pallas``, fp32 or int8 — gets async
 serving for free):
 
-* **Request queue + futures** — ``submit(cloud)`` enqueues one request
-  and returns a :class:`ServeFuture` resolved when its dispatch
-  completes; requests are served FIFO.
+* **Request queue + futures** — ``submit(cloud)`` (or, for part
+  segmentation, ``submit(points, category)``) enqueues one request and
+  returns a :class:`ServeFuture` resolved when its dispatch completes;
+  requests are served FIFO.
 * **Pluggable batching policy** — a
   :class:`~repro.serve.policy.BatchPolicy` (``fixed`` | ``deadline``
   from the ``POLICIES`` registry, named by ``PipelineSpec.policy`` /
@@ -282,15 +283,28 @@ class AsyncPointCloudEngine:
 
     # ------------------------------------------------------ sans-IO ----
 
-    def submit(self, points) -> ServeFuture:
-        """Enqueue one [N, 3] cloud; returns its future (FIFO service)."""
+    def submit(self, points, category=None) -> ServeFuture:
+        """Enqueue one request; returns its future (FIFO service).
+
+        A request is one [N, 3] cloud, or for a part-segmentation
+        pipeline (``head="partseg"``) the points [N, 6] (xyz and
+        normals) and the object category: ``submit(points, category)``.
+        """
         if self._closed:
             raise RuntimeError("engine is closed")
-        cloud = np.asarray(points, np.float32)
-        if cloud.shape != (self.cfg.n_points, 3):
-            raise ValueError(
-                f"submit() takes one [N={self.cfg.n_points}, 3] cloud; "
-                f"got shape {cloud.shape}")
+        if self.cfg.head == "partseg":
+            cloud = batching.request_payload(self.cfg, self._seq, points,
+                                             category)
+        else:
+            if category is not None:
+                raise ValueError(
+                    f"request {self._seq}: head={self.cfg.head!r} takes "
+                    f"the cloud alone; got a category too")
+            cloud = np.asarray(points, np.float32)
+            if cloud.shape != (self.cfg.n_points, self.cfg.in_channels):
+                raise ValueError(
+                    f"submit() takes one [N={self.cfg.n_points}, "
+                    f"{self.cfg.in_channels}] cloud; got shape {cloud.shape}")
         fut = ServeFuture(self._seq, self._clock())
         self._seq += 1
         self._queue.append((cloud, fut, None))
@@ -429,8 +443,7 @@ class AsyncPointCloudEngine:
         """Compile the one ``(max_batch, n_points)`` executable ahead of
         traffic (no queue interaction, no LFSR consumption — dispatches
         restart from the seed state anyway).  Returns compile seconds."""
-        dummy = jnp.zeros((self.max_batch, self.cfg.n_points, 3),
-                          jnp.float32)
+        dummy = batching.zeros_batch(self.cfg, self.max_batch)
         t0 = time.perf_counter()
         if self.pipeline.streaming:
             # Streaming dispatches run the collect/cached executables,
@@ -518,8 +531,12 @@ class AsyncPointCloudEngine:
         taken = [self._queue.popleft() for _ in range(n)]
         now = self._clock()
         self.stats.queued_s += sum(now - f.t_submit for _, f, _ in taken)
-        chunk = batching.stack_requests([c for c, _, _ in taken],
-                                        self.cfg.n_points)
+        if self.cfg.head == "partseg":
+            chunk = batching.stack_payloads([c for c, _, _ in taken])
+        else:
+            chunk = batching.stack_requests([c for c, _, _ in taken],
+                                            self.cfg.n_points,
+                                            self.cfg.in_channels)
         batch, pad = batching.pad_to_batch(chunk, self.max_batch)
         stream = [s for _, _, s in taken]
         cache_in = None
@@ -566,7 +583,7 @@ class AsyncPointCloudEngine:
 
     # ------------------------------------------------ asyncio shell ----
 
-    async def classify_async(self, points) -> np.ndarray:
+    async def classify_async(self, points, category=None) -> np.ndarray:
         """Submit one cloud and await its logits row (a read-only host
         ``np.ndarray``, as :meth:`ServeFuture.result` returns).
 
@@ -582,7 +599,7 @@ class AsyncPointCloudEngine:
                     afut.set_result(fut.result())
             loop.call_soon_threadsafe(settle)
 
-        self.submit(points).add_done_callback(on_done)
+        self.submit(points, category).add_done_callback(on_done)
         return await afut
 
     async def serve_loop(self, tick_s: float = 0.001) -> None:

@@ -8,6 +8,12 @@ ragged->fixed plumbing lives here exactly once: queue normalization,
 ``max_batch`` chunking, zero pad-to-batch, request stacking, and the
 stats schema both engines report.
 
+A request is a payload: one ``[N, 3]`` cloud, or for a part-segmentation
+pipeline (``head="partseg"``) a tuple ``(points [N, C], category)``;
+tuples are stacked and padded array by array (:func:`request_payload`,
+:func:`stack_payloads`, :func:`pad_to_batch`), and a one-array payload
+keeps the plain-array path.
+
 Pad lanes are computed but never returned, and under ``spec.serving()``
 semantics (shared URS sampler + per-sample normalization) they cannot
 leak: a real request's logits are bit-identical no matter what occupies
@@ -89,8 +95,10 @@ def _request_shapes(clouds) -> str:
     return ", ".join(shapes)
 
 
-def as_point_queue(points, n_points: int) -> jnp.ndarray:
-    """Normalize a ragged classify() input to a [R, N, 3] float32 queue.
+def as_point_queue(points, n_points: int, channels: int = 3
+                   ) -> jnp.ndarray:
+    """Normalize a ragged classify() input to a [R, N, 3] float32 queue
+    (``[R, N, channels]`` for points that carry more than xyz).
 
     Accepts a [R, N, 3] array, a single [N, 3] cloud, a list of clouds,
     or an empty input (R == 0 passes through as an empty queue).
@@ -103,18 +111,40 @@ def as_point_queue(points, n_points: int) -> jnp.ndarray:
         pts = jnp.asarray(points, jnp.float32)
     except (ValueError, TypeError):
         raise ValueError(
-            f"classify() takes [N={n_points}, 3] clouds of one shape; "
-            f"got a ragged or malformed request list with shapes "
+            f"classify() takes [N={n_points}, {channels}] clouds of one "
+            f"shape; got a ragged or malformed request list with shapes "
             f"[{_request_shapes(points)}]") from None
     if pts.size == 0:
-        return pts.reshape(0, n_points, 3)
+        return pts.reshape(0, n_points, channels)
     if pts.ndim == 2:
         pts = pts[None]
-    if pts.ndim != 3 or pts.shape[1:] != (n_points, 3):
+    if pts.ndim != 3 or pts.shape[1:] != (n_points, channels):
         raise ValueError(
-            f"engine is fixed-shape: expected [R, N={n_points}, 3] "
-            f"(or one [N, 3] cloud), got {tuple(pts.shape)}")
+            f"engine is fixed-shape: expected [R, N={n_points}, "
+            f"{channels}] (or one [N, {channels}] cloud), got "
+            f"{tuple(pts.shape)}")
     return pts
+
+
+def as_category_queue(category, requests: int, n_categories: int
+                      ) -> jnp.ndarray:
+    """The categories of a classify() queue of ``requests`` clouds, one
+    int32 per request; ``ValueError`` names the first request whose
+    category is missing or out of ``[0, n_categories)``."""
+    if category is None:
+        raise ValueError("classify(): head='partseg' takes (points, "
+                         "category); the categories are missing")
+    cats = np.asarray(category)
+    cats = cats.reshape(1) if cats.ndim == 0 else cats
+    if cats.shape != (requests,) or not np.issubdtype(cats.dtype,
+                                                      np.integer):
+        raise ValueError(f"classify(): expected {requests} integer "
+                         f"categories, one per request; got {category!r}")
+    bad = np.nonzero((cats < 0) | (cats >= n_categories))[0]
+    if bad.size:
+        raise ValueError(f"request {int(bad[0])}: the category must be in "
+                         f"[0, {n_categories}); got {int(cats[bad[0]])}")
+    return jnp.asarray(cats, jnp.int32)
 
 
 def check_shard_batch(max_batch: int, data_shards: int) -> None:
@@ -127,15 +157,21 @@ def check_shard_batch(max_batch: int, data_shards: int) -> None:
             f"split across the device mesh)")
 
 
-def split_queue(pts: jnp.ndarray, max_batch: int) -> Iterator[jnp.ndarray]:
-    """Split a [R, N, 3] queue into <= ``max_batch`` chunks, in order."""
+def split_queue(pts, max_batch: int) -> Iterator[jnp.ndarray]:
+    """Split a [R, N, 3] queue (or a tuple of per-request arrays) into
+    <= ``max_batch`` chunks, in order."""
+    if isinstance(pts, tuple):
+        for i in range(0, pts[0].shape[0], max_batch):
+            yield tuple(a[i:i + max_batch] for a in pts)
+        return
     for i in range(0, pts.shape[0], max_batch):
         yield pts[i:i + max_batch]
 
 
-def pad_to_batch(chunk: jnp.ndarray, max_batch: int
-                 ) -> Tuple[jnp.ndarray, int]:
-    """Zero-pad a [r <= max_batch, N, 3] chunk to the one dispatch shape.
+def pad_to_batch(chunk, max_batch: int) -> Tuple[object, int]:
+    """Zero-pad a [r <= max_batch, N, 3] chunk to the one dispatch shape
+    (a tuple of request arrays: each along its request axis; a pad
+    lane's category is 0).
 
     Returns ``(padded [max_batch, N, 3], n_pad)``.  The fixed shape is
     load-bearing twice over: it keeps the engines on a single jitted
@@ -143,19 +179,25 @@ def pad_to_batch(chunk: jnp.ndarray, max_batch: int
     guaranteed within one executable — it is what makes results
     independent of how the queue was partitioned into dispatches.
     """
-    r, n = chunk.shape[0], chunk.shape[1]
+    if isinstance(chunk, tuple):
+        padded = [pad_to_batch(a, max_batch) for a in chunk]
+        return tuple(a for a, _ in padded), padded[0][1]
+    r = chunk.shape[0]
     pad = max_batch - r
     if pad < 0:
         raise ValueError(f"chunk of {r} requests exceeds the fixed "
                          f"dispatch shape max_batch={max_batch}")
     if pad:
         chunk = jnp.concatenate(
-            [chunk, jnp.zeros((pad, n, 3), jnp.float32)], axis=0)
+            [chunk, jnp.zeros((pad,) + chunk.shape[1:], chunk.dtype)],
+            axis=0)
     return chunk, pad
 
 
-def stack_requests(clouds: Sequence, n_points: int) -> jnp.ndarray:
-    """Stack single [N, 3] request clouds into a [r, N, 3] chunk.
+def stack_requests(clouds: Sequence, n_points: int, channels: int = 3
+                   ) -> jnp.ndarray:
+    """Stack single [N, 3] request clouds into a [r, N, 3] chunk
+    (``[N, channels]`` for points that carry more than xyz).
 
     Every cloud is shape-checked *before* ``np.stack`` so a ragged
     request list raises a ``ValueError`` naming the offending shapes
@@ -164,10 +206,52 @@ def stack_requests(clouds: Sequence, n_points: int) -> jnp.ndarray:
     """
     arrs = [np.asarray(c, np.float32) for c in clouds]
     bad = [(i, a.shape) for i, a in enumerate(arrs)
-           if a.shape != (n_points, 3)]
+           if a.shape != (n_points, channels)]
     if bad:
         raise ValueError(
-            f"requests must be [N={n_points}, 3] clouds; got "
+            f"requests must be [N={n_points}, {channels}] clouds; got "
             + "; ".join(f"request {i}: shape {s}" for i, s in bad[:4])
             + (f" (+{len(bad) - 4} more)" if len(bad) > 4 else ""))
     return jnp.asarray(np.stack(arrs, axis=0))
+
+
+def request_payload(cfg, request: int, points, category):
+    """One part-segmentation request as its pipeline takes it:
+    ``(points [N, in_channels] float32, category int32)``.
+
+    Raises ``ValueError`` naming ``request`` for a missing category, a
+    category out of ``[0, n_categories)``, or points of another shape.
+    """
+    n, c = cfg.n_points, cfg.in_channels
+    cloud = np.asarray(points, np.float32)
+    if cloud.shape != (n, c):
+        raise ValueError(
+            f"request {request}: head={cfg.head!r} takes points "
+            f"[N={n}, {c}] (xyz and normals); got shape {cloud.shape}")
+    if category is None:
+        raise ValueError(
+            f"request {request}: head={cfg.head!r} takes (points, "
+            f"category); the category is missing")
+    cat = np.asarray(category)
+    if (cat.shape != () or not np.issubdtype(cat.dtype, np.integer)
+            or not 0 <= int(cat) < cfg.n_categories):
+        raise ValueError(
+            f"request {request}: the category must be one integer in "
+            f"[0, {cfg.n_categories}); got {category!r}")
+    return cloud, cat.astype(np.int32)
+
+
+def stack_payloads(payloads: Sequence[tuple]) -> tuple:
+    """Stack checked multi-array requests (:func:`request_payload`)
+    array by array: ``[(points, category), ...] -> ([r, N, C], [r])``."""
+    return tuple(jnp.asarray(np.stack(arrays, axis=0))
+                 for arrays in zip(*payloads))
+
+
+def zeros_batch(cfg, batch: int):
+    """An all-zero dispatch of the pipeline's request signature (the
+    warm-up shape)."""
+    points = jnp.zeros((batch, cfg.n_points, cfg.in_channels), jnp.float32)
+    if cfg.head == "partseg":
+        return points, jnp.zeros((batch,), jnp.int32)
+    return points

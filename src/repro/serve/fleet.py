@@ -194,8 +194,9 @@ class PipelineFleet:
         fut.add_done_callback(settle)
         return fut
 
-    def submit(self, tenant: str, points) -> ServeFuture:
-        """Route + admit one ``[N, 3]`` cloud for ``tenant``.
+    def submit(self, tenant: str, points, category=None) -> ServeFuture:
+        """Route + admit one ``[N, 3]`` cloud for ``tenant`` (for a
+        part-segmentation tier, its points and category).
 
         Returns the request's future on admission; raises
         :class:`Overloaded` on a shed (typed, counted in
@@ -203,7 +204,8 @@ class PipelineFleet:
         unknown tenant.
         """
         state, replica = self._route_admit(tenant)
-        return self._settle_admitted(state, replica.engine.submit(points))
+        return self._settle_admitted(state,
+                                     replica.engine.submit(points, category))
 
     def open_stream(self, tenant: str, *, max_age=None):
         """A :class:`~repro.serve.streaming.AsyncStreamSession` for
@@ -343,7 +345,7 @@ class PipelineFleet:
 
     # ------------------------------------------------ asyncio shell ----
 
-    async def classify_async(self, tenant: str, points):
+    async def classify_async(self, tenant: str, points, category=None):
         """Submit one cloud for ``tenant`` and await its logits row, a
         read-only host ``np.ndarray`` (needs :meth:`serve_loop`
         running).  ``Overloaded`` propagates to the caller synchronously
@@ -357,7 +359,7 @@ class PipelineFleet:
                     afut.set_result(fut.result())
             loop.call_soon_threadsafe(settle)
 
-        self.submit(tenant, points).add_done_callback(on_done)
+        self.submit(tenant, points, category).add_done_callback(on_done)
         return await afut
 
     async def serve_loop(self, tick_s: float = 0.001) -> None:

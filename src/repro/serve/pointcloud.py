@@ -90,8 +90,7 @@ class PointCloudEngine:
         """Compile the ``(max_batch, n_points)`` executable — the one
         shape ``classify`` dispatches — ahead of traffic (does not
         consume LFSR state).  Returns compile seconds."""
-        b = self.max_batch
-        dummy = jnp.zeros((b, self.cfg.n_points, 3), jnp.float32)
+        dummy = batching.zeros_batch(self.cfg, self.max_batch)
         t0 = time.perf_counter()
         logits, _ = self.pipeline.infer(dummy, jnp.array(self._lfsr))
         logits.block_until_ready()
@@ -101,7 +100,7 @@ class PointCloudEngine:
 
     # ------------------------------------------------------- serving ----
 
-    def _chunk_queue(self, pts: jnp.ndarray) -> List[jnp.ndarray]:
+    def _chunk_queue(self, pts) -> List[jnp.ndarray]:
         """Host-side queue prep: split to ``max_batch`` chunks, zero-pad
         the last (shared core in ``repro.serve.batching``).  Kept out of
         the serve timer — it is array plumbing, not device throughput."""
@@ -112,13 +111,16 @@ class PointCloudEngine:
             chunks.append(chunk)
         return chunks
 
-    def classify(self, points) -> jnp.ndarray:
+    def classify(self, points, category=None) -> jnp.ndarray:
         """Classify a ragged queue of point clouds.
 
         Args:
           points: [R, N, 3] array (or list of [N, 3] clouds) with
             N == cfg.n_points; R is arbitrary — the queue is chunked to
-            ``max_batch`` and the last chunk zero-padded.
+            ``max_batch`` and the last chunk zero-padded.  For
+            ``head="partseg"``, [R, N, in_channels] (xyz and normals).
+          category: for ``head="partseg"`` only, the [R] object
+            categories, one per cloud.
 
         Returns: logits [R, n_classes] — rows only for the R real
         requests; pad lanes are computed but never returned.
@@ -128,15 +130,26 @@ class PointCloudEngine:
         ``stats.serve_s``); padding/conversion lands in ``stats.host_s``.
         """
         t_host = time.perf_counter()
-        pts = batching.as_point_queue(points, self.cfg.n_points)
+        cfg = self.cfg
+        if cfg.head == "partseg":
+            pts = batching.as_point_queue(points, cfg.n_points,
+                                          cfg.in_channels)
+            queue = (pts, batching.as_category_queue(
+                category, pts.shape[0], cfg.n_categories))
+        else:
+            if category is not None:
+                raise ValueError(f"classify(): head={cfg.head!r} takes "
+                                 f"the clouds alone; got categories too")
+            pts = queue = batching.as_point_queue(points, cfg.n_points,
+                                                  cfg.in_channels)
         if pts.shape[0] == 0:                       # drained queue
-            if self.cfg.head == "seg":
+            if self.cfg.head in ("seg", "partseg"):
                 return jnp.zeros(
                     (0, self.cfg.n_points, self.cfg.n_classes),
                     jnp.float32)
             return jnp.zeros((0, self.cfg.n_classes), jnp.float32)
         r = pts.shape[0]
-        chunks = self._chunk_queue(pts)
+        chunks = self._chunk_queue(queue)
         self.stats.host_s += time.perf_counter() - t_host
 
         t_enqueue = time.perf_counter()
@@ -152,10 +165,11 @@ class PointCloudEngine:
         self.stats.requests += r
         return jnp.concatenate(out, axis=0)
 
-    def predict(self, points) -> jnp.ndarray:
+    def predict(self, points, category=None) -> jnp.ndarray:
         """Top-1 class ids for a ragged queue — [R] for the cls head,
-        [R, n_points] for seg."""
-        return jnp.argmax(self.classify(points), axis=-1).astype(jnp.int32)
+        [R, n_points] for seg and partseg."""
+        return jnp.argmax(self.classify(points, category),
+                          axis=-1).astype(jnp.int32)
 
     def open_stream(self, *, max_age=None, batch=None):
         """A blocking :class:`~repro.serve.streaming.StreamSession` over

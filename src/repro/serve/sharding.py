@@ -101,7 +101,8 @@ def shard_forward(fwd: Callable, spec,
                   cache_in: bool = False,
                   cache_out: bool = False) -> Tuple[Callable, Mesh]:
     """Wrap a built ``fwd(params, pts, lfsr)`` in a data-parallel
-    ``shard_map`` dispatch over ``spec.data_shards`` devices.
+    ``shard_map`` dispatch over ``spec.data_shards`` devices (``pts``
+    one batch array, or a tuple of them, each scattered by lanes).
 
     Returns ``(dispatch, mesh)``; ``dispatch`` has the same signature
     and — given the lane-mapped serving walk — bit-identical results.
@@ -149,7 +150,9 @@ def shard_forward(fwd: Callable, spec,
 
     def dispatch(params, pts, lfsr, *extra):
         with context.use_mesh(mesh):
-            batch = pts.shape[0]
+            # A multi-array request (points, category) splits array by
+            # array: P("data") is a prefix of the whole payload.
+            batch = jax.tree_util.tree_leaves(pts)[0].shape[0]
             if batch % spec.data_shards:
                 raise ValueError(
                     f"data_shards={spec.data_shards} must divide the "
