@@ -8,6 +8,10 @@ fixed seed (``PM.pointmlp_init``).  One chip runs, in order:
 
   kernels      the int8 matmul and kNN Pallas kernels against their
                references at plan shapes;
+  fps-walk     the whole-walk FPS kernel against XLA's ``sampling.fps``
+               on 64 seeded clouds at every FPS stage of the benchmark's
+               Elite and part-segmentation configurations: clouds may
+               differ only where ``decisions.py`` finds an FPS step open;
   lite-int8    Lite (512 points, URS, W8A8, ``backend="pallas"``): a
                ragged queue through ``PointCloudEngine``, checked against
                the same W8A8 forward in plain XLA and against the ``ref``
@@ -42,14 +46,17 @@ import jax.numpy as jnp
 import numpy as np
 
 # This checkout's sources only: a copy of this file alone must fail.
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
+ROOT = pathlib.Path(__file__).resolve().parent
+sys.path.insert(0, str(ROOT / "src"))
 
 from repro.api import (FleetSpec, TenantSpec, build, elite_spec,  # noqa: E402
                        lite_spec)
+from repro.core import sampling  # noqa: E402
 from repro.core.quant import compute_scale, quantize  # noqa: E402
 from repro.data import pointclouds  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.kernels import ref as kernel_ref  # noqa: E402
+from repro.kernels.fps import fps_walk_pallas  # noqa: E402
 from repro.kernels.knn import knn_pallas  # noqa: E402
 from repro.launch.profile import configure_compile_cache  # noqa: E402
 from repro.models import pointmlp as PM  # noqa: E402
@@ -67,6 +74,11 @@ ASYNC_REQUESTS = 3
 STREAM_FRAMES = 4
 STREAM_DRIFT = 0.01       # per-frame rigid motion of make_stream
 STREAM_THRESHOLD = 0.1    # drift vs the key frame that still replays
+FPS_CLOUDS = 64
+#: The benchmark's configurations and modules naming their FPS stages.
+BENCH = ROOT / "benchmarks" / "chip"
+FPS_CONFIGS = (("pointmlp-elite", "decisions"),
+               ("pointmlp-partseg", "partseg_decisions"))
 
 #: Pallas fp32 vs the ref backend, both at "highest" matmul precision:
 #: f32 rounding through ~20 layers, far below a broken kernel (O(1)).
@@ -213,6 +225,64 @@ def phase_kernels(_cache: CacheEvents) -> None:
           f"{err!r}", flush=True)
     require(idx.shape == (s, kk), f"knn_pallas: shape {idx.shape}")
     require(err <= 1e-4, f"knn_pallas: neighbour distances off by {err}")
+
+
+def fps_stages(config: str, module: str):
+    """(points in, samples out) of each FPS stage of a benchmark
+    configuration, as its decisions module walks them."""
+    import importlib
+    if str(BENCH) not in sys.path:
+        sys.path.append(str(BENCH))
+    c = json.loads((BENCH / "configs" / f"{config}.json").read_text())
+    outs = importlib.import_module(module).stage_samples(c)
+    return list(zip([c["n_points"]] + outs[:-1], outs))
+
+
+def accepted_walks(cloud: np.ndarray, m: int):
+    """The FPS walks ``decisions.py`` accepts for one cloud: the walk in
+    float64, and each walk taking the runner-up at one of its
+    ``MAX_OPEN`` most open steps (one flip per stage, as its paths)."""
+    import decisions
+    pts = cloud.astype(np.float64)
+    found = []
+    exact = decisions._fps(pts, m, 0, None, found)
+    walks = [exact]
+    for o in sorted(found, key=lambda o: o.ratio)[:decisions.MAX_OPEN]:
+        walk = decisions._fps(pts, m, 0, o, [])
+        if walk is not None:
+            walks.append(walk)
+    return walks
+
+
+def phase_fps_walk(cache: CacheEvents) -> None:
+    """The whole-walk FPS kernel against XLA's reference walk on the
+    chip; a cloud may differ only at an FPS step that float32 rounding
+    leaves open (``decisions.py``'s tolerance), and then the kernel's
+    walk must be one the benchmark's check accepts."""
+    xla = jax.jit(sampling.fps_batched, static_argnums=1)
+    for config, module in FPS_CONFIGS:
+        for n, m in fps_stages(config, module):
+            pts = clouds(n, FPS_CLOUDS, salt=n * 7 + m)
+            mark = cache.mark()
+            t0 = time.perf_counter()
+            got = np.asarray(fps_walk_pallas(pts, m))
+            want = np.asarray(xla(pts, m))
+            print(f"setup fps-walk {n}->{m}: "
+                  f"{time.perf_counter() - t0:.3f}s {cache.since(mark)}",
+                  flush=True)
+            host = np.asarray(pts)
+            differ = [b for b in range(FPS_CLOUDS)
+                      if not np.array_equal(got[b], want[b])]
+            outside = [b for b in differ
+                       if not any(np.array_equal(got[b], w)
+                                  for w in accepted_walks(host[b], m))]
+            print(f"fps-walk {config} {n}->{m}: {len(differ)} of "
+                  f"{FPS_CLOUDS} clouds differ from xla, {len(outside)} "
+                  f"outside the open decisions", flush=True)
+            require(got.shape == (FPS_CLOUDS, m),
+                    f"fps-walk {n}->{m}: shape {got.shape}")
+            require(not outside, f"fps-walk {config} {n}->{m}: clouds "
+                    f"{outside} differ outside the open decisions")
 
 
 def phase_lite(cache: CacheEvents) -> None:
@@ -410,7 +480,8 @@ def main(argv=None) -> int:
     cache = CacheEvents()
 
     phases = ((phase_sharded, phase_fleet) if args.chips == 4 else
-              (phase_kernels, phase_lite, phase_elite, phase_stream))
+              (phase_kernels, phase_fps_walk, phase_lite, phase_elite,
+               phase_stream))
     t0 = time.perf_counter()
     for phase in phases:
         phase(cache)

@@ -99,7 +99,16 @@ register_fused_op = FUSED_OPS.register
 
 @register_sampler("fps")
 def _fps_sampler(xyz, n_samples: int, lfsr_state, shared: bool):
-    """Farthest Point Sampling — data-dependent, stateless."""
+    """Farthest Point Sampling — data-dependent, stateless.
+
+    On a TPU each cloud's whole walk is one VMEM-resident Pallas kernel
+    program (``fps_walk_pallas``); elsewhere the reference walk, an XLA
+    loop of one argmax per step.  Both give ``sampling.fps``'s indices.
+    """
+    from repro.kernels.tuning import on_tpu
+    if on_tpu():
+        from repro.kernels.fps import fps_walk_pallas
+        return fps_walk_pallas(xyz, n_samples), lfsr_state
     from repro.core import sampling
     return sampling.fps_batched(xyz, n_samples), lfsr_state
 

@@ -20,7 +20,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.api import build, elite_spec, lite_spec
-from repro.kernels.fps import fps_pallas
+from repro.api.spec import PipelineSpec
+from repro.kernels.fps import fps_pallas, fps_walk_pallas
 from repro.kernels.fused_linear import fused_linear_pallas
 from repro.kernels.grouped_transfer import grouped_transfer_pallas
 from repro.kernels.int8_matmul import int8_matmul_pallas
@@ -91,6 +92,16 @@ def test_fps_compiles(one_chip, variant):
                    _sds(one_chip, (n, 3)))
 
 
+@pytest.mark.parametrize("n,s", [(1024, 512), (2048, 512), (32, 8)],
+                         ids=["elite", "partseg", "smallest-stage"])
+def test_fps_walk_compiles(one_chip, n, s):
+    """The whole-walk kernel the ``fps`` sampler runs on a TPU, at a
+    serving lane's batch of one."""
+    text = _compiled_text(lambda p: fps_walk_pallas(p, s, interpret=False),
+                          _sds(one_chip, (1, n, 3)))
+    assert "fps_walk" in text
+
+
 @pytest.mark.parametrize("variant", sorted(SPECS))
 def test_knn_compiles(one_chip, variant):
     s, n, k = plan_shapes(SPECS[variant])["knn"]
@@ -138,6 +149,41 @@ def test_serving_forward_compiles(one_chip, spec):
         params, _sds(one_chip, (8, spec.n_points, 3)),
         _sds(one_chip, (8,), jnp.uint32)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _partseg_spec():
+    """The benchmark's published part-segmentation configuration."""
+    import json
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+            / "chip" / "configs" / "pointmlp-partseg.json")
+    c = json.loads(path.read_text())
+    fields = {k: tuple(tuple(x) if isinstance(x, list) else x for x in v)
+              if isinstance(v, list) else v
+              for k, v in c.items() if k in PipelineSpec.__dataclass_fields__}
+    return PipelineSpec(**fields).serving()
+
+
+@pytest.mark.parametrize("model", ["elite", "partseg"])
+def test_serving_forward_compiles_through_fps_walk(one_chip, monkeypatch,
+                                                  model):
+    """With the platform probe reporting a TPU, the ``fps`` sampler
+    takes the whole-walk kernel: the serving forward of each FPS cell,
+    at its published widths and batch 8, compiles with it."""
+    from repro.kernels import tuning
+    monkeypatch.setattr(tuning, "on_tpu", lambda: True)
+    spec = (elite_spec(40).replace(backend="pallas").serving()
+            if model == "elite" else _partseg_spec())
+    pipe = build(spec, PM.pointmlp_init(jax.random.PRNGKey(0),
+                                        spec.to_model_config()))
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), pipe.params)
+    points = _sds(one_chip, (8, spec.n_points, spec.in_channels))
+    payload = (points if model == "elite"
+               else (points, _sds(one_chip, (8,), jnp.int32)))
+    text = pipe._fn.lower(params, payload,
+                          _sds(one_chip, (8,), jnp.uint32)).compile().as_text()
+    assert "fps_walk" in text
 
 
 def test_chip_smoke_refuses_without_tpu(capsys):
