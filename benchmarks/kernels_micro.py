@@ -83,35 +83,3 @@ def rows() -> List[Tuple[str, float, str]]:
     out.append(("urs_lfsr_512->256", us_urs, f"fps={us_fps:.0f}us "
                 f"speedup={us_fps/max(us_urs,1e-9):.0f}x"))
     return out
-
-
-def tile_rows(quick: bool = True):
-    """Kernel tile-sweep rows (``ktune_<kernel>``): the micro-autotuner
-    (:mod:`repro.tune.kernels`) swept over its quick tile grid at a
-    CI-sized plan's shapes, interpret mode.
-
-    Returns ``(name, us_per_call, derived, spec)`` tuples — ``spec``
-    carries the chosen tile and the swept shape as plain numerics, so
-    the BENCH artifact records *which* tiles won, not just how fast.
-    The CPU µs are interpret-mode regression anchors, same caveat as
-    :func:`rows`.
-    """
-    from repro.api.spec import lite_spec
-    from repro.data import pointclouds
-    from repro.tune import kernels as ktune
-
-    base = lite_spec(pointclouds.N_CLASSES).replace(
-        n_points=128, embed_dim=16, k_neighbors=8)
-    shapes = ktune.plan_shapes(base)
-    out = []
-    for kernel in sorted(shapes):
-        table = ktune.sweep(kernel, shapes[kernel], quick=quick,
-                            iters=1, interpret=True)
-        (tile, us), worst = table[0], table[-1]
-        tile_list = list(tile) if isinstance(tile, tuple) else tile
-        out.append((
-            f"ktune_{kernel}", us,
-            f"tile={tile};grid={len(table)};"
-            f"worst={worst[1]:.0f}us;shape={shapes[kernel]}",
-            {"tile": tile_list, "shape": list(shapes[kernel])}))
-    return out

@@ -59,22 +59,6 @@ def bench_kernels() -> None:
         _emit(name, us, derived.replace(",", ";"))
 
 
-def bench_kernel_tuning() -> None:
-    """``ktune_<kernel>`` rows: the tile micro-autotuner's quick sweep
-    (tiny tile grid, interpret mode).  Each row's ``us_per_call`` is
-    the winning tile's time and its ``spec`` dict records the chosen
-    tile + swept shape as numerics — the artifact-tracked record of
-    which tiles win on this platform, gated like any other row by
-    ``scripts/bench_diff.py``."""
-    from benchmarks import kernels_micro
-    from repro.tune import artifact as art
-    for name, us, derived, spec in kernels_micro.tile_rows(quick=True):
-        derived = derived.replace(",", ";")
-        print(f"{name},{us:.1f},{derived}", flush=True)
-        _ROWS.append(art.new_row(name, us_per_call=us, derived=derived,
-                                 spec=spec))
-
-
 def bench_table1(steps: int) -> None:
     from benchmarks import table1_compression
     t0 = time.time()
@@ -503,11 +487,7 @@ def main() -> None:
                     help="skip the training-based tables")
     ap.add_argument("--tune-quick", action="store_true",
                     help="run only the roofline-guided spec autotuner "
-                         "(CI-sized search space) + the kernel tile "
-                         "sweep rows")
-    ap.add_argument("--kernels-quick", action="store_true",
-                    help="run only the kernel tile micro-autotuner "
-                         "sweep (the CI kernel-smoke step)")
+                         "(CI-sized search space)")
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write the rows as a schema-versioned "
                          "BENCH_<rev>.json artifact (repro.tune.artifact)")
@@ -518,19 +498,12 @@ def main() -> None:
     configure_compile_cache()
 
     print("name,us_per_call,derived")
-    if args.kernels_quick:
-        bench_kernel_tuning()
-        if args.json:
-            _write_json(args.json)
-        return
     if args.tune_quick:
         bench_tune_quick()
-        bench_kernel_tuning()
         if args.json:
             _write_json(args.json)
         return
     bench_kernels()
-    bench_kernel_tuning()
     bench_table2()
     bench_table3()
     bench_specs()

@@ -51,6 +51,7 @@ sys.path.insert(0, str(ROOT / "src"))
 
 from repro.api import (FleetSpec, TenantSpec, build, elite_spec,  # noqa: E402
                        lite_spec)
+from repro.api.plan import plan_shapes  # noqa: E402
 from repro.core import sampling  # noqa: E402
 from repro.core.quant import compute_scale, quantize  # noqa: E402
 from repro.data import pointclouds  # noqa: E402
@@ -64,7 +65,6 @@ from repro.serve.async_engine import AsyncPointCloudEngine  # noqa: E402
 from repro.serve.fleet import PipelineFleet  # noqa: E402
 from repro.serve.pointcloud import PointCloudEngine  # noqa: E402
 from repro.serve.streaming import replay_reference  # noqa: E402
-from repro.tune.kernels import plan_shapes  # noqa: E402
 
 SEED = 0
 N_CLASSES = 40

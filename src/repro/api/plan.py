@@ -53,7 +53,6 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 from repro.api import registry
 from repro.api.spec import N_STAGES as _N_STAGES
 from repro.core.quant import QuantConfig, is_quantizable_leaf_path
-from repro.kernels.tuning import DEFAULT_TUNING, KernelTuning
 
 _PALLAS_BACKENDS = ("pallas_interpret", "pallas")
 
@@ -235,10 +234,6 @@ class StagePlan:
     fused_group: str = "none"
     head: str = "cls"               # "cls" | "seg" | "partseg"
     stream: bool = False            # cache-aware mapping-op variants
-    #: Resolved per-kernel tile sizes (spec.kernel_tuning or the
-    #: defaults) — already bound onto the ops' fn callables; kept here
-    #: for introspection and cost modeling.
-    tuning: KernelTuning = DEFAULT_TUNING
 
     # ------------------------------------------------- introspection ----
 
@@ -294,18 +289,11 @@ class StagePlan:
         rows = []
         fused = {op.stage for op in self.ops
                  if isinstance(op, FusedGroupTransferOp)}
-        t = self.tuning
         for s in range(_N_STAGES):
             row = (f"stage {s + 1}: {self.stage_precision[s]}/"
                    f"{self.stage_backend[s]}")
-            if self.stage_backend[s] in _PALLAS_BACKENDS:
-                tm, tk, tn = (t.int8_matmul
-                              if self.stage_precision[s] == "int8"
-                              else t.fused_linear)
-                row += f" [tiles {tm}x{tk}x{tn}]"
             if s in fused:
-                row += (f" [group->transfer fused: {self.fused_group}, "
-                        f"tile_s={t.grouped_transfer}]")
+                row += f" [group->transfer fused: {self.fused_group}]"
             if self.stream:
                 row += " [stream-cached mapping]"
             rows.append(row)
@@ -447,19 +435,16 @@ def _quant_for(spec, precision: str,
 
     The int8 x pallas lowering rule lives here: an int8 op on a pallas
     backend runs the int8 Pallas matmul kernel (int32 MXU accumulation,
-    epilogue dequant) with the spec's KernelTuning tiles bound — the
-    former RPA101 warn-and-fall-back to the reference int8 matmul is
-    retired.  ``pallas_interpret`` pins interpret mode (the CPU
-    correctness canary); ``pallas`` compiles.
+    epilogue dequant) — the former RPA101 warn-and-fall-back to the
+    reference int8 matmul is retired.  ``pallas_interpret`` pins
+    interpret mode (the CPU correctness canary); ``pallas`` compiles.
     """
     if precision != "int8":
         return None
     if backend in _PALLAS_BACKENDS:
-        tuning = getattr(spec, "kernel_tuning", None) or DEFAULT_TUNING
         return QuantConfig(w_bits=min(spec.w_bits, 8), a_bits=spec.a_bits,
                            per_channel=spec.per_channel,
                            symmetric=spec.symmetric, backend="int8_pallas",
-                           tiles=tuning.int8_matmul,
                            interpret=(backend == "pallas_interpret"))
     return QuantConfig(w_bits=min(spec.w_bits, 8), a_bits=spec.a_bits,
                        per_channel=spec.per_channel,
@@ -549,39 +534,26 @@ def lower(spec, cfg) -> StagePlan:
     ``repro.analysis`` lowering passes: error findings raise
     ``ValueError``/``KeyError`` with their ``RPAxxx``-coded message,
     warning findings warn — escalated in-tree by the pytest gate.
-
-    Kernel tuning: the spec's :class:`~repro.kernels.tuning.KernelTuning`
-    (or the defaults) is bound here, per op — pallas CBR ops get their
-    fused-matmul tiles partial-applied onto the backend callable, int8
-    pallas ops carry their tiles on the op's QuantConfig, and a fused
-    group->transfer op gets its sample-tile size — so tile choices are a
-    lowering axis, visible in ``describe()`` and the cost model, not
-    kwarg defaults buried in kernels/.
+    Tile sizes are not a lowering decision: each Pallas kernel owns its
+    own.
     """
     # Deferred import: repro.analysis.passes imports this module.
     from repro.analysis.passes import enforce_spec
     enforce_spec(spec, scopes=("lowering",))
     stage_prec, stage_back = resolve_stage_fields(spec)
-    tuning = getattr(spec, "kernel_tuning", None) or DEFAULT_TUNING
     fused_key = getattr(spec, "fused_group", "none") or "none"
     fused_fn = (registry.FUSED_OPS.get(fused_key)
                 if fused_key != "none" else None)
-    if fused_fn is not None:
-        fused_fn = functools.partial(fused_fn,
-                                     tile_s=tuning.grouped_transfer)
     head = getattr(spec, "head", "cls") or "cls"
     stream = bool(getattr(spec, "stream", False))
 
     def make_cbr(path, stage, act) -> CBROp:
         precision = spec.precision if stage is None else stage_prec[stage]
         backend = spec.backend if stage is None else stage_back[stage]
-        fn = registry.BACKENDS.get(backend)
-        if backend in _PALLAS_BACKENDS:
-            fn = functools.partial(fn, tiles=tuning.fused_linear)
         return CBROp(path=tuple(path), stage=stage, act=act,
                      precision=precision, backend=backend,
                      quant=_quant_for(spec, precision, backend),
-                     fn=fn)
+                     fn=registry.BACKENDS.get(backend))
 
     ops = _build_ops(cfg, make_cbr,
                      _quant_for(spec, spec.precision, spec.backend),
@@ -590,8 +562,7 @@ def lower(spec, cfg) -> StagePlan:
     return StagePlan(name=spec.name, ops=ops,
                      stage_precision=stage_prec, stage_backend=stage_back,
                      precision=spec.precision, backend=spec.backend,
-                     fused_group=fused_key, head=head, stream=stream,
-                     tuning=tuning)
+                     fused_group=fused_key, head=head, stream=stream)
 
 
 def lower_config(cfg, backend_fn: Callable,
@@ -619,6 +590,27 @@ def lower_config(cfg, backend_fn: Callable,
                      stage_precision=(precision,) * _N_STAGES,
                      stage_backend=(backend_key,) * _N_STAGES,
                      precision=precision, backend=backend_key, head=head)
+
+
+def plan_shapes(spec) -> Dict[str, tuple]:
+    """The shapes each Pallas kernel of the point pipeline runs at under
+    ``spec``, per serving lane, as :func:`lower`'s ops see them.
+
+    The matmul kernels (``fused_linear``, ``int8_matmul``) at the
+    FLOP-heaviest stage transfer, ``(S * k, cfg.transfer_in(C_prev), C)``;
+    the mapping kernels at stage 1: ``fps`` ``(N, S)``, ``knn``
+    ``(S, N, k)`` and ``grouped_transfer`` ``(N, S, k, C_embed)``.
+    """
+    cfg = spec.to_model_config()
+    k = cfg.k_neighbors
+    c_prev = (cfg.embed_dim,) + cfg.stage_dims[:-1]
+    transfers = [(cfg.stage_samples[s] * k, cfg.transfer_in(c_prev[s]),
+                  cfg.stage_dims[s]) for s in range(_N_STAGES)]
+    mm = max(transfers, key=lambda t: t[0] * t[1] * t[2])
+    n, s0 = cfg.n_points, cfg.stage_samples[0]
+    return {"fused_linear": mm, "int8_matmul": mm,
+            "grouped_transfer": (n, s0, k, cfg.embed_dim),
+            "fps": (n, s0), "knn": (s0, n, k)}
 
 
 # ------------------------------------------- fingerprint / search space -
@@ -654,21 +646,13 @@ def _inherited_stage_fields(spec) -> Tuple[Tuple[str, ...], Tuple[str, ...]]:
 
 def spec_label(spec) -> str:
     """Compact human-readable rendering of the *searched* axes of a spec
-    (the tuner's row name — stable across revisions for the CI diff).
-    A non-default :class:`~repro.kernels.tuning.KernelTuning` appends a
-    ``/kt=`` token so tile-only twins keep distinct artifact rows."""
+    (the tuner's row name — stable across revisions for the CI diff)."""
     prec, back = _inherited_stage_fields(spec)
-    label = (f"{spec.sampler}/{spec.grouper}"
-             f"/prec={'.'.join(prec)}+{spec.precision}"
-             f"/be={back[0] if len(set(back)) == 1 else '.'.join(back)}"
-             f"/fg={getattr(spec, 'fused_group', 'none')}"
-             f"/ds={spec.data_shards}")
-    kt = getattr(spec, "kernel_tuning", None)
-    if kt is not None and kt != DEFAULT_TUNING:
-        tm, tk, tn = kt.fused_linear
-        label += (f"/kt={tm}x{tk}x{tn}.gt{kt.grouped_transfer}"
-                  f".f{kt.fps}.k{kt.knn}")
-    return label
+    return (f"{spec.sampler}/{spec.grouper}"
+            f"/prec={'.'.join(prec)}+{spec.precision}"
+            f"/be={back[0] if len(set(back)) == 1 else '.'.join(back)}"
+            f"/fg={getattr(spec, 'fused_group', 'none')}"
+            f"/ds={spec.data_shards}")
 
 
 #: Default per-stage precision ladder searched by the autotuner: the
@@ -688,24 +672,18 @@ def enumerate_plan_space(base,
                          fused_groups: Iterable = ("none",),
                          data_shards: Iterable = (1,),
                          samplers: Optional[Iterable] = None,
-                         groupers: Optional[Iterable] = None,
-                         kernel_tunings: Iterable = (None,)) -> List:
+                         groupers: Optional[Iterable] = None) -> List:
     """Enumerate the valid spec search space around ``base``.
 
     The cross product ``stage_precision`` x ``stage_backend`` x
-    ``fused_group`` x ``data_shards`` x sampler x grouper x
-    ``kernel_tuning``, filtered by the ``repro.analysis`` lowering
-    passes: any candidate with an error finding (fused group->transfer
-    with an int8 stage or non-knn grouper, unknown registry keys, a
-    broken stream contract) *or* a warning finding leaves the space.
-    int8 stages on pallas backends are *valid* points (they lower to
-    the int8 Pallas matmul — the former RPA101 fallback warning is
-    retired).  ``kernel_tunings`` entries are
-    :class:`~repro.kernels.tuning.KernelTuning` instances (``None``
-    inherits ``base.kernel_tuning``) — ``repro.tune.kernels`` feeds
-    measured best-tile tables in here so the roofline search ranks tile
-    candidates alongside the other axes.  Deterministic order — the
-    cross product in argument order — so the autotuner's candidate
+    ``fused_group`` x ``data_shards`` x sampler x grouper, filtered by
+    the ``repro.analysis`` lowering passes: any candidate with an error
+    finding (fused group->transfer with an int8 stage or non-knn
+    grouper, unknown registry keys, a broken stream contract) *or* a
+    warning finding leaves the space.  int8 stages on pallas backends
+    are *valid* points (they lower to the int8 Pallas matmul — the
+    former RPA101 fallback warning is retired).  Deterministic order —
+    the cross product in argument order — so the autotuner's candidate
     ranking is reproducible.
     """
     # Deferred import: repro.analysis.passes imports this module.
@@ -713,16 +691,14 @@ def enumerate_plan_space(base,
     samplers = tuple(samplers) if samplers is not None else (base.sampler,)
     groupers = tuple(groupers) if groupers is not None else (base.grouper,)
     out = []
-    for sp, sb, fg, ds, sam, grp, kt in itertools.product(
+    for sp, sb, fg, ds, sam, grp in itertools.product(
             tuple(tuple(p) for p in stage_precisions),
             tuple(tuple(b) for b in stage_backends),
             tuple(fused_groups), tuple(data_shards),
-            samplers, groupers, tuple(kernel_tunings)):
+            samplers, groupers):
         spec = base.replace(stage_precision=sp, stage_backend=sb,
                             fused_group=fg, data_shards=ds,
                             sampler=sam, grouper=grp)
-        if kt is not None:
-            spec = spec.replace(kernel_tuning=kt)
         if analyze_spec(spec, scopes=("lowering",)):
             continue
         out.append(spec)

@@ -239,7 +239,7 @@ def _cbr_ref(p, x, quant, act: bool):
     return jax.nn.relu(y) if act else y
 
 
-def _cbr_fused_pallas(p, x, quant, act: bool, interpret, tiles=None):
+def _cbr_fused_pallas(p, x, quant, act: bool, interpret):
     """Fused fp32 layers through the single-pass ``fused_linear`` kernel.
 
     Only a *frozen* fp32 layer takes the fused kernel — plain 2-D matmul
@@ -249,9 +249,6 @@ def _cbr_fused_pallas(p, x, quant, act: bool, interpret, tiles=None):
     dispatches on the QuantConfig the plan bound to the op); anything
     else (unfused BN, fake-quant) falls back to the pure reference
     path, so one backend entry serves mixed trees.
-
-    ``tiles`` is an optional (tm, tk, tn) override bound at lowering
-    time from the spec's :class:`~repro.kernels.tuning.KernelTuning`.
     """
     import jax.numpy as jnp
     w = p["w"]
@@ -261,10 +258,9 @@ def _cbr_fused_pallas(p, x, quant, act: bool, interpret, tiles=None):
         b = p.get("b")
         if b is None:
             b = jnp.zeros((w.shape[1],), w.dtype)
-        tm, tk, tn = tiles if tiles is not None else (128, 128, 128)
         y = fused_linear_pallas(x.reshape(-1, w.shape[0]), w, b,
                                 activation="relu" if act else "none",
-                                tm=tm, tk=tk, tn=tn, interpret=interpret)
+                                interpret=interpret)
         return y.reshape(*x.shape[:-1], w.shape[1])
     return _cbr_ref(p, x, quant, act)
 
@@ -281,7 +277,7 @@ BACKENDS.register("pallas")(
 @register_fused_op("grouped_transfer")
 def _grouped_transfer(p, xyz, feats, idx, k: int, affine_params,
                       mode: str, per_sample_norm: bool, act: bool = True,
-                      tile_s: int = 64, interpret=None):
+                      interpret=None):
     """Fused gather + geometric-affine-normalize + matmul+bias+ReLU.
 
     The stage-plan lowering of a ``GroupOp`` + transfer-``CBROp`` pair:
@@ -289,13 +285,12 @@ def _grouped_transfer(p, xyz, feats, idx, k: int, affine_params,
     mode on CPU) gathers KNN neighborhoods, normalizes them, and runs
     the transfer layer without the ``[B, S, k, 2C]`` grouped tensor
     ever round-tripping through HBM.  Requires a fused fp32 transfer
-    layer (plan lowering enforces this).  ``tile_s``/``interpret`` are
-    bound at lowering time from the spec's KernelTuning / stage
-    backend.
+    layer (plan lowering enforces this).  ``interpret=None`` resolves
+    from the platform.
     """
     from repro.kernels.grouped_transfer import fused_group_transfer
     return fused_group_transfer(xyz, feats, idx, k, affine_params, mode,
-                                per_sample_norm, p, act=act, tile_s=tile_s,
+                                per_sample_norm, p, act=act,
                                 interpret=interpret)
 
 

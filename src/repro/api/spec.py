@@ -21,8 +21,6 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Tuple
 
-from repro.kernels.tuning import KernelTuning
-
 PRECISIONS = ("fp32", "int8")
 AFFINE_MODES = ("affine", "norm", "center")
 HEADS = ("cls", "seg", "partseg")
@@ -123,11 +121,6 @@ class PipelineSpec:
     # key frame, same units as the cloud coordinates). ----
     stream: bool = False
     stream_drift_threshold: float = 0.0
-    # ---- kernel tuning: per-kernel Pallas tile sizes
-    # (``repro.kernels.tuning.KernelTuning``), bound per op at lowering
-    # time; None = the kernels' defaults.  ``repro.tune.kernels`` picks
-    # these by timed sweeps at the plan's actual shapes. ----
-    kernel_tuning: Optional[KernelTuning] = None
     # ---- batch semantics ----
     shared_urs: bool = False
     per_sample_norm: bool = False
@@ -176,12 +169,6 @@ class PipelineSpec:
         if not isinstance(self.fused_group, str):
             raise ValueError(f"fused_group must be a FUSED_OPS registry "
                              f"key or 'none', got {self.fused_group!r}")
-        if (self.kernel_tuning is not None
-                and not isinstance(self.kernel_tuning, KernelTuning)):
-            raise ValueError(
-                f"kernel_tuning must be a repro.kernels.tuning."
-                f"KernelTuning (or None for defaults), "
-                f"got {self.kernel_tuning!r}")
         if self.head not in HEADS:
             raise ValueError(f"head must be one of {HEADS}, "
                              f"got {self.head!r}")

@@ -27,10 +27,8 @@ class QuantConfig:
     symmetric: bool = True
     # matmul implementation: fake (QAT), int8_ref (jnp int8), int8_pallas
     backend: str = "fake"
-    # int8_pallas only: (tm, tk, tn) tile sizes and interpret-mode flag,
-    # bound at lowering time from the spec's KernelTuning / stage backend
-    # (None = kernel defaults / platform-resolved interpret).
-    tiles: Optional[Tuple[int, int, int]] = None
+    # int8_pallas only: interpret-mode flag, bound at lowering time from
+    # the stage backend (None = platform-resolved).
     interpret: Optional[bool] = None
 
     @property

@@ -1,15 +1,12 @@
-"""FPS Pallas kernels (the baseline sampler HLS4PC replaces).
+"""FPS Pallas kernel (the baseline sampler HLS4PC replaces).
 
 FPS is inherently sequential over samples, but each iteration's hot loop —
 fold the distance-to-the-last-centroid into the running minimum over all N
-points — is data-parallel.
-
-* :func:`fps_walk_pallas` runs a whole walk in one kernel invocation per
-  cloud: the coordinates and the running minimum stay in VMEM for every
-  step, so a step costs a few vector ops and reductions, not a trip
-  round a device loop.  The ``fps`` sampler uses it on a TPU.
-* :func:`fps_update_pallas` / :func:`fps_pallas` run one kernel per step
-  inside an XLA loop (the argmax stays in XLA); no pipeline uses them.
+points — is data-parallel.  :func:`fps_walk_pallas` runs a whole walk in
+one kernel invocation per cloud: the coordinates and the running minimum
+stay in VMEM for every step, so a step costs a few vector ops and
+reductions, not a trip round a device loop.  The ``fps`` sampler uses it
+on a TPU.
 """
 from __future__ import annotations
 
@@ -20,64 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.tuning import resolve_interpret
-
-
-def _fps_update_kernel(p_ref, last_ref, d_ref, o_ref):
-    p = p_ref[:].astype(jnp.float32)              # [C, TN]
-    last = last_ref[:].astype(jnp.float32)        # [C, 1]
-    diff = p - last
-    d = jnp.sum(diff * diff, axis=0, keepdims=True)   # [1, TN]
-    o_ref[:] = jnp.minimum(d_ref[:], d)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def fps_update_pallas(points_t: jnp.ndarray, last: jnp.ndarray,
-                      dists: jnp.ndarray, tile_n: int = 512,
-                      interpret=None) -> jnp.ndarray:
-    """points_t [C, N] (transposed layout), last [C], dists [1, N] ->
-    new running-min dists [1, N].  ``interpret=None`` resolves from the
-    platform (compiled on TPU, interpreter elsewhere)."""
-    interpret = resolve_interpret(interpret)
-    c, n = points_t.shape
-    n_pad = -n % tile_n
-    pp = jnp.pad(points_t, ((0, 0), (0, n_pad)))
-    dp = jnp.pad(dists, ((0, 0), (0, n_pad)))
-    grid = ((n + n_pad) // tile_n,)
-    out = pl.pallas_call(
-        _fps_update_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((c, tile_n), lambda i: (0, i)),
-            pl.BlockSpec((c, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, tile_n), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n + n_pad), jnp.float32),
-        interpret=interpret,
-    )(pp, last[:, None], dp)
-    return out[:, :n]
-
-
-def fps_pallas(points: jnp.ndarray, n_samples: int,
-               interpret=None, tile_n: int = 512) -> jnp.ndarray:
-    """Full FPS using the Pallas distance-update step. [N, C] -> [S]."""
-    interpret = resolve_interpret(interpret)
-    n = points.shape[0]
-    pt = points.T                                  # [C, N] TPU-native
-    dists0 = jnp.full((1, n), jnp.inf, jnp.float32)
-    idxs0 = jnp.zeros((n_samples,), jnp.int32)
-
-    def body(i, carry):
-        dists, idxs = carry
-        last = points[idxs[i - 1]]
-        dists = fps_update_pallas(pt, last, dists, tile_n=tile_n,
-                                  interpret=interpret)
-        nxt = jnp.argmax(dists[0]).astype(jnp.int32)
-        return dists, idxs.at[i].set(nxt)
-
-    _, idxs = jax.lax.fori_loop(1, n_samples, body, (dists0, idxs0))
-    return idxs
-
 
 _LANES = 128
 _SUBLANES = 8

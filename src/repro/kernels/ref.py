@@ -6,8 +6,6 @@ interpret mode).
 """
 from __future__ import annotations
 
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
 
@@ -17,15 +15,6 @@ from repro.core.knn import knn_select, pairwise_sqdist
 def knn_ref(samples: jnp.ndarray, points: jnp.ndarray, k: int) -> jnp.ndarray:
     """[S, C], [N, C] -> [S, k] ascending-distance neighbor indices."""
     return knn_select(pairwise_sqdist(samples, points), k)
-
-
-def fps_update_ref(points: jnp.ndarray, last: jnp.ndarray,
-                   dists: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One FPS step: fold the distance-to-last into the running min and
-    return (new_dists [N], argmax int32)."""
-    d = jnp.sum((points - last[None, :]) ** 2, axis=-1)
-    nd = jnp.minimum(dists, d)
-    return nd, jnp.argmax(nd).astype(jnp.int32)
 
 
 def int8_matmul_ref(x_q: jnp.ndarray, w_q: jnp.ndarray,

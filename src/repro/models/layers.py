@@ -40,7 +40,6 @@ def _matmul(x: jnp.ndarray, w, quant: Optional[QuantConfig]) -> jnp.ndarray:
             from repro.kernels import ops as kops
             return kops.int8_matmul(x, w["q"], w["scale"],
                                     a_bits=quant.a_bits,
-                                    tiles=quant.tiles,
                                     interpret=quant.interpret)
         # W8 reference path: dequantized weight matmul (W8A16/W8A32).
         wd = (w["q"].astype(x.dtype) * w["scale"].astype(x.dtype))

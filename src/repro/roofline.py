@@ -252,64 +252,6 @@ def _op_precision(plan, op: str) -> str:
     return plan.precision            # embed / head
 
 
-def _ceil_waste(dim: int, tile: int) -> float:
-    """ceil(dim/tile)*tile / dim — the padded-grid inflation of one
-    matmul dimension under one tile size."""
-    if dim <= 0:
-        return 1.0
-    import math
-    return (math.ceil(dim / tile) * tile) / dim
-
-
-def _tile_waste(plan, cfg, op: str) -> float:
-    """Padding-waste multiplier (>= 1) on an op's compute term when it
-    lowers to a tiled Pallas matmul: the grid rounds every matmul dim
-    up to its tile, so a 96-wide layer on a 128-tile does 128/96 of
-    the useful MACs.  This is what makes ``estimate_plan`` rank
-    :class:`~repro.kernels.tuning.KernelTuning` candidates — smaller
-    tiles waste less padding on narrow layers (the memory term is left
-    alone: padded lanes stream from the same HBM lines).  Ops on
-    non-Pallas backends return 1.0.
-    """
-    from repro.api.plan import _PALLAS_BACKENDS
-    t = plan.tuning
-    if op.startswith("stage"):
-        s = int(op.split(".")[0][len("stage"):]) - 1
-        if plan.stage_backend[s] not in _PALLAS_BACKENDS:
-            return 1.0
-        tm, tk, tn = (t.int8_matmul if plan.stage_precision[s] == "int8"
-                      else t.fused_linear)
-        kind = op.split(".")[1]
-        smp, c = cfg.stage_samples[s], cfg.stage_dims[s]
-        c_prev = cfg.stage_dims[s - 1] if s else cfg.embed_dim
-        k = cfg.k_neighbors
-        if kind == "group":
-            return 1.0               # gather/normalize, not a matmul
-        if kind == "transfer":
-            return (_ceil_waste(smp * k, tm)
-                    * _ceil_waste(cfg.transfer_in(c_prev), tk)
-                    * _ceil_waste(c, tn))
-        # pre/pos residual blocks: two matmuls (c->mid, mid->c); mean
-        # of the two directions' waste.
-        mid = max(1, int(c * cfg.res_expansion))
-        m = smp * k if kind == "pre" else smp
-        w1 = _ceil_waste(m, tm) * _ceil_waste(c, tk) * _ceil_waste(mid, tn)
-        w2 = _ceil_waste(m, tm) * _ceil_waste(mid, tk) * _ceil_waste(c, tn)
-        return 0.5 * (w1 + w2)
-    if op == "head" and plan.backend in _PALLAS_BACKENDS:
-        tm, tk, tn = (t.int8_matmul if plan.precision == "int8"
-                      else t.fused_linear)
-        m = cfg.n_points if plan.head == "seg" else 1
-        c_in = (cfg.embed_dim + 2 * cfg.stage_dims[-1]
-                if plan.head == "seg" else cfg.stage_dims[-1])
-        w1 = _ceil_waste(m, tm) * _ceil_waste(c_in, tk) * _ceil_waste(512, tn)
-        w2 = _ceil_waste(m, tm) * _ceil_waste(512, tk) * _ceil_waste(256, tn)
-        w3 = (_ceil_waste(m, tm) * _ceil_waste(256, tk)
-              * _ceil_waste(cfg.n_classes, tn))
-        return (w1 + w2 + w3) / 3.0
-    return 1.0
-
-
 def estimate_plan(plan, cfg, hw: HardwareModel = TPU_V5E,
                   *, data_shards: int = 1) -> PlanEstimate:
     """Score a compiled :class:`repro.api.plan.StagePlan` statically.
@@ -321,18 +263,14 @@ def estimate_plan(plan, cfg, hw: HardwareModel = TPU_V5E,
     Precision overrides therefore shrink both terms (int8 peak is
     higher *and* int8 weights are smaller) and a fused group->transfer
     stage drops the grouped tensor's traffic, so the estimate ranks
-    the autotuner's search space the way the paper's DSE does.  Ops
-    that lower to tiled Pallas matmuls additionally pay the tile
-    padding waste of the plan's :class:`KernelTuning`
-    (:func:`_tile_waste`), so ``spec.kernel_tuning`` is a ranked axis
-    of the search like any other.
+    the autotuner's search space the way the paper's DSE does.
     """
     rows = []
     for row in plan.cost_breakdown(cfg):
         prec = _op_precision(plan, row["op"])
         peak = hw.peak_int8_ops if prec == "int8" else hw.peak_flops
         nbytes = row["w_bytes"] + row["act_bytes"]
-        t_c = row["flops"] * _tile_waste(plan, cfg, row["op"]) / peak
+        t_c = row["flops"] / peak
         t_m = nbytes / hw.hbm_bw
         rows.append({"op": row["op"], "precision": prec,
                      "flops": row["flops"], "w_bytes": row["w_bytes"],

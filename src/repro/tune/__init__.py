@@ -5,9 +5,7 @@
 
 Submodules: ``search`` (the estimate -> rank -> measure driver),
 ``frontier`` (deterministic Pareto selection), ``artifact``
-(schema-versioned JSON writer/reader/validator), ``kernels`` (the
-per-kernel tile micro-autotuner — timed sweeps at the plan's shapes
-feeding ``spec.kernel_tuning``).  The CLI entry is
+(schema-versioned JSON writer/reader/validator).  The CLI entry is
 ``python benchmarks/run.py --tune-quick --json BENCH_<rev>.json``; two
 artifacts diff with ``scripts/bench_diff.py`` (the CI regression gate).
 """
@@ -17,15 +15,12 @@ from repro.tune.artifact import (SCHEMA, ArtifactError, new_artifact,
                                  new_row, read_artifact, resolve_rev,
                                  validate_artifact, write_artifact)
 from repro.tune.frontier import dominates, mark_frontier, pareto_frontier
-from repro.tune.kernels import (best_tile, plan_shapes, plan_tuning,
-                                sweep, tuning_candidates)
 from repro.tune.search import (ANCHOR_NAME, Candidate, anchor_spec,
                                quick_space, tune)
 
 __all__ = [
     "ANCHOR_NAME", "ArtifactError", "Candidate", "SCHEMA", "anchor_spec",
-    "best_tile", "dominates", "mark_frontier", "new_artifact", "new_row",
-    "pareto_frontier", "plan_shapes", "plan_tuning", "quick_space",
-    "read_artifact", "resolve_rev", "sweep", "tune", "tuning_candidates",
-    "validate_artifact", "write_artifact",
+    "dominates", "mark_frontier", "new_artifact", "new_row",
+    "pareto_frontier", "quick_space", "read_artifact", "resolve_rev",
+    "tune", "validate_artifact", "write_artifact",
 ]

@@ -46,20 +46,16 @@ class Candidate:
 def quick_space(base) -> List[Any]:
     """The CI-sized search space around ``base``: precision ladder x
     {ref, pallas-interpret} x {unfused, fused group->transfer} x
-    {1, N}-way sharding (N only when this host has devices for it) x
-    the static kernel-tile candidate set (ranked by the roofline
-    estimate's tile-padding-waste term)."""
+    {1, N}-way sharding (N only when this host has devices for it)."""
     import jax
 
-    from repro.tune.kernels import tuning_candidates
     n_dev = jax.device_count()
     shards = (1,) if n_dev < 2 else (1, min(8, n_dev))
     return stage_plan.enumerate_plan_space(
         base,
         stage_backends=(("ref",) * 4, ("pallas_interpret",) * 4),
         fused_groups=("none", "grouped_transfer"),
-        data_shards=shards,
-        kernel_tunings=tuning_candidates(quick=True))
+        data_shards=shards)
 
 
 def anchor_spec(base):
@@ -149,16 +145,6 @@ def _row(cand: Candidate) -> Dict[str, Any]:
         "fused_group": cand.spec.fused_group,
         "data_shards": cand.spec.data_shards,
         "n_points": cand.spec.n_points}
-    # Resolved tile choices as plain numerics — the artifact's record
-    # of which KernelTuning the candidate lowered with (defaults when
-    # the spec carries none).
-    from repro.kernels.tuning import DEFAULT_TUNING
-    kt = cand.spec.kernel_tuning or DEFAULT_TUNING
-    spec_fields["kernel_tuning"] = {
-        "fused_linear": list(kt.fused_linear),
-        "int8_matmul": list(kt.int8_matmul),
-        "grouped_transfer": kt.grouped_transfer,
-        "fps": kt.fps, "knn": kt.knn}
     est = cand.estimate
     return art.new_row(
         cand.label, fingerprint=cand.fingerprint, derived=derived,

@@ -4,7 +4,7 @@ No chip is attached: the TPU compiler compiles for a topology that is
 only described, which catches what interpret mode cannot (a primitive
 Mosaic has no lowering for, an unaligned block, too much VMEM).  Every
 kernel is compiled with ``interpret=False`` at the shapes a real-width
-plan runs (``repro.tune.kernels.plan_shapes``), and every compiled
+plan runs (``repro.api.plan.plan_shapes``), and every compiled
 program must call a Mosaic kernel (``tpu_custom_call``).  Code that asks
 ``jax.default_backend()`` still sees the CPU here, so the kernels are
 called directly with ``interpret=False`` rather than through a pipeline
@@ -21,15 +21,30 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.api import build, elite_spec, lite_spec
 from repro.api.spec import PipelineSpec
-from repro.kernels.fps import fps_pallas, fps_walk_pallas
+from repro.api.plan import plan_shapes
+from repro.kernels.fps import fps_walk_pallas
 from repro.kernels.fused_linear import fused_linear_pallas
 from repro.kernels.grouped_transfer import grouped_transfer_pallas
 from repro.kernels.int8_matmul import int8_matmul_pallas
 from repro.kernels.knn import knn_pallas
 from repro.models import pointmlp as PM
-from repro.tune.kernels import plan_shapes
 
-SPECS = {"lite": lite_spec(40), "elite": elite_spec(40)}
+
+def _partseg_spec():
+    """The benchmark's published part-segmentation configuration."""
+    import json
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+            / "chip" / "configs" / "pointmlp-partseg.json")
+    c = json.loads(path.read_text())
+    fields = {k: tuple(tuple(x) if isinstance(x, list) else x for x in v)
+              if isinstance(v, list) else v
+              for k, v in c.items() if k in PipelineSpec.__dataclass_fields__}
+    return PipelineSpec(**fields).serving()
+
+
+SPECS = {"lite": lite_spec(40), "elite": elite_spec(40),
+         "partseg": _partseg_spec()}
 
 
 @pytest.fixture(scope="module")
@@ -85,11 +100,14 @@ def test_int8_matmul_compiles(one_chip, variant):
         _sds(one_chip, (1, n)))
 
 
-@pytest.mark.parametrize("variant", sorted(SPECS))
-def test_fps_compiles(one_chip, variant):
-    n, s = plan_shapes(SPECS[variant])["fps"]
-    _compiled_text(lambda p: fps_pallas(p, s, interpret=False),
-                   _sds(one_chip, (n, 3)))
+@pytest.mark.parametrize("n,s", [(512, 256), (256, 128), (128, 64)],
+                         ids=["stage2", "stage3", "stage4"])
+def test_fps_compiles(one_chip, n, s):
+    """Elite's stage 2-4 walks through the whole-walk kernel, at a
+    serving lane's batch of one."""
+    text = _compiled_text(lambda p: fps_walk_pallas(p, s, interpret=False),
+                          _sds(one_chip, (1, n, 3)))
+    assert "fps_walk" in text
 
 
 @pytest.mark.parametrize("n,s", [(1024, 512), (2048, 512), (32, 8)],
@@ -151,19 +169,6 @@ def test_serving_forward_compiles(one_chip, spec):
     assert "tpu_custom_call" in text
 
 
-def _partseg_spec():
-    """The benchmark's published part-segmentation configuration."""
-    import json
-    import pathlib
-    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
-            / "chip" / "configs" / "pointmlp-partseg.json")
-    c = json.loads(path.read_text())
-    fields = {k: tuple(tuple(x) if isinstance(x, list) else x for x in v)
-              if isinstance(v, list) else v
-              for k, v in c.items() if k in PipelineSpec.__dataclass_fields__}
-    return PipelineSpec(**fields).serving()
-
-
 @pytest.mark.parametrize("model", ["elite", "partseg"])
 def test_serving_forward_compiles_through_fps_walk(one_chip, monkeypatch,
                                                   model):
@@ -173,7 +178,7 @@ def test_serving_forward_compiles_through_fps_walk(one_chip, monkeypatch,
     from repro.kernels import tuning
     monkeypatch.setattr(tuning, "on_tpu", lambda: True)
     spec = (elite_spec(40).replace(backend="pallas").serving()
-            if model == "elite" else _partseg_spec())
+            if model == "elite" else SPECS["partseg"])
     pipe = build(spec, PM.pointmlp_init(jax.random.PRNGKey(0),
                                         spec.to_model_config()))
     params = jax.tree_util.tree_map(

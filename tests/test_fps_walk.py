@@ -26,8 +26,12 @@ def reference(points, n_samples):
     return np.stack([np.asarray(sampling.fps(p, n_samples)) for p in points])
 
 
-@pytest.mark.parametrize("n,s", [(1024, 512), (512, 128), (257, 32),
-                                 (32, 8), (40, 40)])
+@pytest.mark.parametrize("n,s", [
+    (1024, 512), (512, 128), (257, 32), (32, 8), (40, 40), (64, 8),
+    (1024, 128), (2048, 512),
+    # padding remainders: N off the (8, 128) tile, S off a tile row
+    (100, 50), (1000, 250), (1025, 64),
+])
 def test_walk_equals_reference(n, s):
     pts = jax.random.normal(jax.random.fold_in(KEY, n * s), (1, n, 3))
     got = fps_walk_pallas(pts, s)

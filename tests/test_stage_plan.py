@@ -11,16 +11,20 @@ grouped-transfer path is opted into.
 
 Lowering: op-sequence shape, per-stage precision/backend override
 resolution (including the selective int8 export and the int8-Pallas
-matmul routing for int8 x pallas stages), and invalid-override
-``ValueError``/``KeyError``s.
+matmul routing for int8 x pallas stages), invalid-override
+``ValueError``/``KeyError``s, and the per-kernel shapes ``plan_shapes``
+reads off a spec.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import (FUSED_OPS, GROUPERS, PipelineSpec, build, lite_spec,
-                       make_ball_grouper, register_grouper)
+from repro.api import (FUSED_OPS, GROUPERS, PipelineSpec, build, elite_spec,
+                       lite_spec, m2_spec, make_ball_grouper,
+                       register_grouper)
 from repro.api import plan as SP
 from repro.api import registry as R
 from repro.core import knn as knn_core
@@ -230,13 +234,9 @@ class TestLowering:
         plan = SP.lower(spec, spec.to_model_config())
         fns = {op.stage: op.fn for op in plan.cbr_ops()
                if op.stage is not None}
-        # Pallas entries get the spec's tiles bound at lowering time;
-        # the underlying backend fn is still the registered one.
-        from repro.kernels.tuning import DEFAULT_TUNING
-        base = R.BACKENDS.get("pallas_interpret")
-        assert fns[2].func is base.func
+        # Each op runs its stage's registered backend entry as is.
+        assert fns[2] is R.BACKENDS.get("pallas_interpret")
         assert fns[2].keywords["interpret"] is True
-        assert fns[2].keywords["tiles"] == DEFAULT_TUNING.fused_linear
         assert fns[0] is R.BACKENDS.get("ref")
         assert plan.stage_backend == ("ref", "ref", "pallas_interpret",
                                       "ref")
@@ -317,10 +317,10 @@ class TestInvalidOverrides:
     def test_int8_stage_with_pallas_backend_lowers_to_int8_pallas(self):
         """int8 x pallas is a first-class lowering now (RPA101
         retired): the stage's quant config routes the matmuls to the
-        int8 Pallas kernel, tiles bound from the spec's tuning."""
+        int8 Pallas kernel, interpreted on the ``pallas_interpret``
+        backend."""
         import warnings as W
 
-        from repro.kernels.tuning import DEFAULT_TUNING
         spec = tiny_spec(precision="int8",
                          stage_backend=("ref", "ref", "pallas_interpret",
                                         "ref"))
@@ -330,8 +330,46 @@ class TestInvalidOverrides:
         quants = {op.stage: op.quant for op in plan.cbr_ops()
                   if op.stage is not None}
         assert quants[2].backend == "int8_pallas"
-        assert quants[2].tiles == DEFAULT_TUNING.int8_matmul
+        assert quants[2].interpret is True
         assert quants[0].backend == "int8_ref"
+
+
+# ------------------------------------------------------------------ #
+# plan_shapes: the kernel shapes a spec's lowered plan runs          #
+# ------------------------------------------------------------------ #
+
+PLAN_SPECS = {"elite": elite_spec, "m2": m2_spec, "lite": lite_spec}
+
+
+@pytest.mark.parametrize("kernel", ["fused_linear", "int8_matmul",
+                                    "grouped_transfer", "fps", "knn"])
+@pytest.mark.parametrize("variant", ["elite", "m2", "lite", "partseg"])
+def test_plan_shapes_match_lowered_plan(bench_spec, variant, kernel):
+    """Each entry equals the shape read off the lowered plan and the
+    model's weights: the matmuls at the FLOP-heaviest stage transfer
+    (its weight is ``[transfer_in, C]``, so xyz counts under
+    ``use_xyz``), the mapping kernels at stage 1."""
+    spec = (bench_spec("partseg") if variant == "partseg"
+            else PLAN_SPECS[variant]())
+    cfg = spec.to_model_config()
+    plan = SP.lower(spec, cfg)
+    params = jax.eval_shape(lambda key: PM.pointmlp_init(key, cfg),
+                            jax.random.PRNGKey(0))
+    samples = {op.stage: op.n_samples for op in plan.ops
+               if isinstance(op, SP.SampleOp)}
+    ks = {op.stage: op.k for op in plan.ops if isinstance(op, SP.GroupOp)}
+    transfers = [(samples[op.stage] * ks[op.stage],
+                  *SP.param_at(params, op.path)["w"].shape)
+                 for op in plan.ops if isinstance(op, SP.CBROp)]
+    assert len(transfers) == 4
+    embed = next(op.cbr for op in plan.ops if isinstance(op, SP.EmbedOp))
+    c_embed = SP.param_at(params, embed.path)["w"].shape[1]
+    n, s0, k0 = spec.n_points, samples[0], ks[0]
+    want = {"fused_linear": max(transfers, key=math.prod),
+            "int8_matmul": max(transfers, key=math.prod),
+            "grouped_transfer": (n, s0, k0, c_embed),
+            "fps": (n, s0), "knn": (s0, n, k0)}[kernel]
+    assert SP.plan_shapes(spec)[kernel] == want
 
 
 # ------------------------------------------------------------------ #
